@@ -6,9 +6,12 @@ tiling exploits) and checks both backends converge to the same residual.
 Emits ``BENCH_engine.json`` so the engine's perf trajectory has a seed
 point next to the kernel sweep's.
 
-Multi-device rows run in a subprocess with fake host devices (the XLA
-device count must be set before JAX initialises); ``--child`` is that
-subprocess entry and prints one JSON row.
+Multi-device rows run in-process on the real devices, and a row whose
+``k`` exceeds them is skipped.  A process pinned to the CPU
+(``JAX_PLATFORMS=cpu``) runs them instead in one subprocess each, with
+fake host devices (the XLA device count must be set before JAX
+initialises); the parent spawns those before it touches JAX itself.
+``--child`` is that subprocess entry and prints one JSON row.
 """
 from __future__ import annotations
 
@@ -80,7 +83,7 @@ def run_config(n: int, k: int, backend: str, buckets_per_dev: int,
 
 
 def _spawn_child(n, k, backend, buckets_per_dev, headroom) -> dict:
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={k}")
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
@@ -99,11 +102,24 @@ def _spawn_child(n, k, backend, buckets_per_dev, headroom) -> dict:
 
 
 def main(smoke: bool = False, out_path: str = "BENCH_engine.json"):
+    if smoke:
+        grid = [(2**12, 1, 36, 4)]
+    else:
+        grid = [(2**16, 1, 520, 8), (2**16, 8, 72, 8), (2**17, 8, 136, 8)]
+    backends = ("segment_sum", "bsr")
+    # fake-device children first: a parent holding a chip would starve them
+    fake_devices = os.environ.get("JAX_PLATFORMS") == "cpu"
+    child_rows = {
+        (n, k, backend): _spawn_child(n, k, backend, bpd, hr)
+        for n, k, bpd, hr in grid if k > 1 and fake_devices
+        for backend in backends
+    }
+
     import jax
 
-    rows = []
     from benchmarks._meta import std_meta
 
+    n_dev = len(jax.devices())
     meta = std_meta(
         "engine_chunk_rounds",
         seed=1,
@@ -111,22 +127,26 @@ def main(smoke: bool = False, out_path: str = "BENCH_engine.json"):
               "intra_frac=0.92, span_hosts=2)",
         note=("chunk_ms times the steady-state jitted chunk "
               "(chunk_rounds exchange cycles incl. psum_scatter); "
-              "k>1 rows run on fake host devices in a subprocess. "
-              "On CPU the bsr backend runs the einsum tile path; the "
-              "Pallas gather kernel takes over on TPU."),
+              "k>1 rows run on the real devices, or on fake host devices "
+              "in a subprocess when pinned to the CPU. On CPU the bsr "
+              "backend runs the einsum tile path; the Pallas gather "
+              "kernel takes over on TPU."),
         smoke=smoke,
     )
-    if smoke:
-        grid = [(2**12, 1, 36, 4)]
-    else:
-        grid = [(2**16, 1, 520, 8), (2**16, 8, 72, 8), (2**17, 8, 136, 8)]
+    rows = []
     for n, k, bpd, hr in grid:
-        for backend in ("segment_sum", "bsr"):
-            if k == 1:
+        for backend in backends:
+            if (n, k, backend) in child_rows:
+                row = child_rows[(n, k, backend)]
+            elif k > n_dev:
+                row = {"n": n, "k": k, "backend": backend,
+                       "skipped": f"k={k} > {n_dev} devices"}
+                rows.append(row)
+                print(f"[engine] N={n} K={k} {backend}: {row['skipped']}")
+                continue
+            else:
                 row = run_config(n, k, backend, bpd, hr,
                                  n_chunks=2 if smoke else 8)
-            else:
-                row = _spawn_child(n, k, backend, bpd, hr)
             rows.append(row)
             print(f"[engine] N={n} K={k} {backend}: "
                   f"chunk={row['chunk_ms']}ms rounds={row['rounds']} "
@@ -134,6 +154,8 @@ def main(smoke: bool = False, out_path: str = "BENCH_engine.json"):
     # backend pairs must agree on the residual they reach
     for i in range(0, len(rows), 2):
         a, b = rows[i], rows[i + 1]
+        if "skipped" in a or "skipped" in b:
+            continue
         drift = abs(a["residual_after"] - b["residual_after"])
         scale = max(abs(a["residual_after"]), 1e-12)
         agree = drift <= 1e-5 + 1e-2 * scale

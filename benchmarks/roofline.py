@@ -29,9 +29,9 @@ from typing import Dict, List, Optional
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.kernels.tune.model import (  # noqa: E402
-    PLATFORM_SPECS,
     dma_compute_ratio,
     frontier_round_cost,
+    hw_spec,
     ideal_time_s,
     roofline_fraction,
 )
@@ -46,11 +46,11 @@ _MEASURED_COLS = (
 )
 
 
-def analyse_row(row: Dict, bs: int, platform: str) -> Optional[Dict]:
+def analyse_row(row: Dict, bs: int, device_kind: str) -> Optional[Dict]:
     """Roofline terms for one sweep row; None for skipped rows."""
     if "skipped" in row or "n" not in row:
         return None
-    spec = PLATFORM_SPECS.get(platform, PLATFORM_SPECS["cpu"])
+    spec = hw_spec(device_kind)
     n, c = int(row["n"]), int(row["c"])
     n_row_blocks = -(-n // bs)
     out: Dict = {}
@@ -80,10 +80,10 @@ def annotate_payload(payload: Dict) -> Dict:
     full-path fraction keeps a ``full_`` prefix.
     """
     meta = payload.get("meta", {})
-    platform = meta.get("platform", meta.get("backend", "cpu"))
+    device_kind = meta["device_kind"]
     bs = int(meta.get("bs", 128))
     for row in payload.get("rows", []):
-        terms = analyse_row(row, bs, platform)
+        terms = analyse_row(row, bs, device_kind)
         if terms is None:
             continue
         row.setdefault("buffer_depth", 1)
@@ -105,11 +105,11 @@ def build_table(bench_path: str = BENCH_PATH) -> List[Dict]:
     with open(bench_path) as fh:
         payload = json.load(fh)
     meta = payload.get("meta", {})
-    platform = meta.get("platform", meta.get("backend", "cpu"))
+    device_kind = meta["device_kind"]
     bs = int(meta.get("bs", 128))
     table: List[Dict] = []
     for row in payload.get("rows", []):
-        terms = analyse_row(row, bs, platform)
+        terms = analyse_row(row, bs, device_kind)
         if terms is None:
             continue
         skip = terms.get("pallas_skip_us") or terms.get("bsr_full_us")

@@ -223,6 +223,9 @@ def smoke() -> int:
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     full = "--full" in sys.argv
     if "--smoke" in sys.argv:

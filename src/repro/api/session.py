@@ -344,6 +344,55 @@ class _SegmentSumDriver:
                 np.asarray(ops)[:c], int(rounds), res_cols, stats)
 
 
+_BSR_ADVANCE: dict = {}
+
+
+def _bsr_advance():
+    """The jitted ``frontier:pallas`` loop, built once per process.
+
+    The BSR operand, the tolerance and the round limit are arguments, so
+    every resumed ``advance`` (one per ``trace_every`` rounds) replays
+    one compiled program instead of tracing and compiling a new loop.
+    """
+    if _BSR_ADVANCE:
+        return _BSR_ADVANCE["fn"]
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.diffusion import frontier_round_bsr
+
+    @functools.partial(jax.jit, static_argnames=(
+        "gamma", "backend", "interpret", "buffer_depth",
+        "occupancy_threshold"))
+    def advance(state, m, w, out_deg, dang, tol, round_limit, *, gamma,
+                backend, interpret, buffer_depth, occupancy_threshold):
+        def cond(state):
+            f, res, h, t, ops, rounds = state
+            return (res > tol) & (rounds < round_limit)
+
+        def body(state):
+            f, _res, h, t, ops, rounds = state
+            f_new, sent, res = frontier_round_bsr(
+                m, f, w, t, backend=backend, interpret=interpret,
+                buffer_depth=buffer_depth,
+                occupancy_threshold=occupancy_threshold)
+            # the op's threshold predicate is authoritative (the pallas
+            # backend folds t into the weights); sel follows the sent fluid
+            sel = sent != 0
+            dops = jnp.sum(jnp.where(sel, out_deg, 0))
+            dops = dops + jnp.sum((sel & dang).astype(jnp.int32))
+            t_new = jnp.where(jnp.any(sel), t, t / gamma)
+            return (f_new, res, h + sent, t_new,
+                    ops + dops.astype(ops.dtype), rounds + 1)
+
+        return jax.lax.while_loop(cond, body, state)
+
+    _BSR_ADVANCE["fn"] = advance
+    return advance
+
+
 class _BsrFrontierDriver:
     """frontier:pallas — fused BSR frontier rounds (jnp oracle off-TPU)."""
 
@@ -420,38 +469,12 @@ class _BsrFrontierDriver:
         return float(res)
 
     def advance(self, tol: float, round_limit: int) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        from repro.kernels.diffusion import frontier_round_bsr
-
-        m, w, out_deg, dang, gamma = (self.m, self.w, self.out_deg,
-                                      self.dang, self.gamma)
-        op_backend, interpret = self.op_backend, self.interpret
-        buffer_depth = self.buffer_depth
-        occupancy_threshold = self.occupancy_threshold
-
-        def cond(state):
-            f, res, h, t, ops, rounds = state
-            return (res > tol) & (rounds < round_limit)
-
-        def body(state):
-            f, _res, h, t, ops, rounds = state
-            f_new, sent, res = frontier_round_bsr(
-                m, f, w, t, backend=op_backend,
-                interpret=interpret or None,
-                buffer_depth=buffer_depth,
-                occupancy_threshold=occupancy_threshold)
-            # the op's threshold predicate is authoritative (the pallas
-            # backend folds t into the weights); sel follows the sent fluid
-            sel = sent != 0
-            dops = jnp.sum(jnp.where(sel, out_deg, 0))
-            dops = dops + jnp.sum((sel & dang).astype(jnp.int32))
-            any_sel = jnp.any(sel)
-            t_new = jnp.where(any_sel, t, t / gamma)
-            return f_new, res, h + sent, t_new, ops + dops, rounds + 1
-
-        self._state = jax.lax.while_loop(cond, body, self._state)
+        self._state = _bsr_advance()(
+            self._state, self.m, self.w, self.out_deg, self.dang, tol,
+            round_limit, gamma=self.gamma, backend=self.op_backend,
+            interpret=self.interpret or None,
+            buffer_depth=self.buffer_depth,
+            occupancy_threshold=self.occupancy_threshold)
 
     def x(self) -> np.ndarray:
         return np.asarray(self._state[2][: self.n], dtype=np.float64)

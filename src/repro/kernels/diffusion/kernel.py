@@ -42,10 +42,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "VMEM_LIMIT_BYTES",
     "bsr_spmm_pallas",
     "bsr_gather_spmm_pallas",
     "frontier_round_bsr_pallas",
 ]
+
+# Scoped-VMEM limit every kernel here compiles under; the tune model's
+# feasibility check budgets against this same number.  bs=512, C=64,
+# depth=4 needs about 5 MiB by that model.
+VMEM_LIMIT_BYTES = 32 * 2**20
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _mxu_dot(a, b, out_dtype):
+    # f32 tiles times f32 fluid at full f32 precision: a single bf16 MXU
+    # pass would lose ~3 digits per push, far above the solver's |F|_1
+    # certificate.
+    return jnp.dot(a, b, preferred_element_type=out_dtype,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def _kernel(block_row_ref, block_col_ref, blocks_ref, x_ref, o_ref):
@@ -59,9 +74,7 @@ def _kernel(block_row_ref, block_col_ref, blocks_ref, x_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(
-        blocks_ref[0], x_ref[0], preferred_element_type=o_ref.dtype
-    )
+    o_ref[0] += _mxu_dot(blocks_ref[0], x_ref[0], o_ref.dtype)
 
 
 @functools.partial(
@@ -103,6 +116,7 @@ def bsr_spmm_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )
     # output blocks never visited keep uninitialised garbage; mask them in
     # ops.py via the row-occupancy map (cheap [n_row_blocks] bool).
@@ -127,9 +141,7 @@ def _gather_kernel(visit_block_ref, visit_row_ref, visit_col_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(
-        blocks_ref[0], x_ref[0], preferred_element_type=o_ref.dtype
-    )
+    o_ref[0] += _mxu_dot(blocks_ref[0], x_ref[0], o_ref.dtype)
 
 
 def _gather_kernel_dma(visit_block_ref, visit_row_ref, visit_col_ref,
@@ -162,11 +174,9 @@ def _gather_kernel_dma(visit_block_ref, visit_row_ref, visit_col_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    slot = jax.lax.rem(i, depth)
+    slot = jax.lax.rem(i, jnp.int32(depth))
     tile_dma(slot, i).wait()
-    o_ref[...] += jnp.dot(
-        buf_ref[slot], x_ref[0], preferred_element_type=o_ref.dtype
-    )
+    o_ref[0] += _mxu_dot(buf_ref[slot], x_ref[0], o_ref.dtype)
 
     nxt = jnp.minimum(i + depth, n_visits - 1)
 
@@ -225,7 +235,7 @@ def bsr_gather_spmm_pallas(
             num_scalar_prefetch=3,
             grid=(v,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # tile pool stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),  # tile pool stays in HBM
                 pl.BlockSpec((1, bs, c), lambda i, vb, vr, vc: (vc[i], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
@@ -244,6 +254,7 @@ def bsr_gather_spmm_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )
     return fn(visit_block, visit_row, visit_col, blocks, x)
 
@@ -293,13 +304,11 @@ def _frontier_kernel(block_row_ref, block_col_ref, col_active_ref,
         fc = f_col_ref[0]
         sent = jnp.where(jnp.abs(fc) * wt_col_ref[0] > 1.0, fc,
                          jnp.zeros_like(fc))
-        o_ref[0] += jnp.dot(
-            blocks_ref[0], sent, preferred_element_type=o_ref.dtype
-        )
+        o_ref[0] += _mxu_dot(blocks_ref[0], sent, o_ref.dtype)
 
     @pl.when(last)
     def _row_residual():
-        l1_ref[0, 0] = jnp.sum(jnp.abs(o_ref[0]))
+        l1_ref[0] = jnp.sum(jnp.abs(o_ref[0])).reshape(1, 1)
 
 
 def _frontier_kernel_dma(block_row_ref, block_col_ref, col_active_ref,
@@ -344,7 +353,7 @@ def _frontier_kernel_dma(block_row_ref, block_col_ref, col_active_ref,
         )
         o_ref[0] = jnp.where(sel, jnp.zeros_like(fr), fr)
 
-    slot = jax.lax.rem(i, depth)
+    slot = jax.lax.rem(i, jnp.int32(depth))
 
     @pl.when(col_armed(i))
     def _push():
@@ -352,9 +361,7 @@ def _frontier_kernel_dma(block_row_ref, block_col_ref, col_active_ref,
         fc = f_col_ref[0]
         sent = jnp.where(jnp.abs(fc) * wt_col_ref[0] > 1.0, fc,
                          jnp.zeros_like(fc))
-        o_ref[0] += jnp.dot(
-            buf_ref[slot], sent, preferred_element_type=o_ref.dtype
-        )
+        o_ref[0] += _mxu_dot(buf_ref[slot], sent, o_ref.dtype)
 
     # slot is free again (its copy was waited above, or never started);
     # immediately refill it with the tile this slot serves next.
@@ -366,7 +373,7 @@ def _frontier_kernel_dma(block_row_ref, block_col_ref, col_active_ref,
 
     @pl.when(last)
     def _row_residual():
-        l1_ref[0, 0] = jnp.sum(jnp.abs(o_ref[0]))
+        l1_ref[0] = jnp.sum(jnp.abs(o_ref[0])).reshape(1, 1)
 
 
 @functools.partial(
@@ -390,7 +397,9 @@ def frontier_round_bsr_pallas(
 
     Returns ``(f_new, row_l1)`` with ``f_new: [n_row_blocks, bs, C]`` holding
     ``F - sent + P @ sent`` for every *occupied* block row and
-    ``row_l1: [n_row_blocks, 1]`` its per-row |·|_1.  Rows that own no block
+    ``row_l1: [n_row_blocks, 1, 1]`` its per-row |·|_1 (3-D so that its
+    ``(1, 1, 1)`` block spans the array's last two dims, as Mosaic
+    requires of a block that is not (8, 128)-aligned).  Rows that own no block
     are left uninitialised (garbage) in BOTH outputs by design — the ops.py
     wrapper substitutes the kept fluid ``F - sent`` there via the
     row-occupancy map.  The square tiling (n_col_blocks == n_row_blocks)
@@ -407,7 +416,7 @@ def frontier_round_bsr_pallas(
     c = f.shape[-1]
     out_shape = (
         jax.ShapeDtypeStruct((n_row_blocks, bs, c), f.dtype),
-        jax.ShapeDtypeStruct((n_row_blocks, 1), f.dtype),
+        jax.ShapeDtypeStruct((n_row_blocks, 1, 1), f.dtype),
     )
     fluid_specs = [
         pl.BlockSpec((1, bs, c), lambda i, br, bc, ca: (bc[i], 0, 0)),
@@ -417,7 +426,7 @@ def frontier_round_bsr_pallas(
     ]
     out_specs = (
         pl.BlockSpec((1, bs, c), lambda i, br, bc, ca: (br[i], 0, 0)),
-        pl.BlockSpec((1, 1), lambda i, br, bc, ca: (br[i], 0)),
+        pl.BlockSpec((1, 1, 1), lambda i, br, bc, ca: (br[i], 0, 0)),
     )
     if buffer_depth == 1:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -435,7 +444,7 @@ def frontier_round_bsr_pallas(
             num_scalar_prefetch=3,
             grid=(n_blocks,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # tile pool stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),  # tile pool stays in HBM
                 *fluid_specs,
             ],
             out_specs=out_specs,
@@ -452,5 +461,6 @@ def frontier_round_bsr_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )
     return fn(block_row, block_col, col_active, blocks, f, wt, f, wt)

@@ -23,8 +23,14 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@jax.tree_util.register_pytree_node_class
 class BsrMatrix:
-    """Host-prepared BSR operand: static structure + device arrays."""
+    """Host-prepared BSR operand: static structure + device arrays.
+
+    A pytree (arrays are leaves, ``n_row_blocks``/``bs`` static), so a
+    jitted round takes it as an argument and :meth:`from_arrays` can
+    wrap ``jax.ShapeDtypeStruct`` leaves to lower a round without data.
+    """
 
     def __init__(self, blocks, block_row, block_col, n_row_blocks, bs):
         self.blocks = jnp.asarray(blocks)
@@ -35,6 +41,25 @@ class BsrMatrix:
         occ = np.zeros(n_row_blocks, dtype=bool)
         occ[np.asarray(block_row)] = True
         self.row_occupied = jnp.asarray(occ)
+
+    @classmethod
+    def from_arrays(cls, blocks, block_row, block_col, row_occupied,
+                    n_row_blocks, bs) -> "BsrMatrix":
+        """Wrap ready leaves as they are (device arrays, tracers or
+        ``jax.ShapeDtypeStruct``); no conversion, no upload."""
+        m = object.__new__(cls)
+        m.blocks, m.block_row, m.block_col = blocks, block_row, block_col
+        m.row_occupied = row_occupied
+        m.n_row_blocks, m.bs = int(n_row_blocks), int(bs)
+        return m
+
+    def tree_flatten(self):
+        return ((self.blocks, self.block_row, self.block_col,
+                 self.row_occupied), (self.n_row_blocks, self.bs))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls.from_arrays(*children, *aux)
 
     @property
     def n_blocks(self) -> int:
@@ -168,7 +193,7 @@ def frontier_round_bsr(
         occ = m.row_occupied
         f_new = jnp.where(occ[:, None, None], out, keep).reshape(f2.shape)
         keep_l1 = jnp.sum(jnp.abs(keep), axis=(1, 2))
-        res = jnp.sum(jnp.where(occ, row_l1[:, 0], keep_l1))
+        res = jnp.sum(jnp.where(occ, row_l1[:, 0, 0], keep_l1))
     else:
         raise ValueError(f"unknown frontier backend {backend!r}")
     if squeeze:

@@ -13,6 +13,7 @@ from .model import (  # noqa: F401
     dma_compute_ratio,
     frontier_round_cost,
     gather_spmm_cost,
+    hw_spec,
     ideal_time_s,
     roofline_fraction,
     vmem_bytes,

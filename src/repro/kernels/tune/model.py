@@ -8,17 +8,21 @@ of truth for that model; the autotuner's feasibility check, the
 ``benchmarks/roofline.py`` table and the per-config ``roofline_fraction``
 emitted into BENCH_kernels.json all derive from it.
 
-Platform peak numbers are *nominal* datasheet values (TPU v5e for the tpu
-entry); they anchor the roofline fraction, they are not measurements.
+Peaks are published datasheet values keyed by ``jax.Device.device_kind``
+(each entry names its source); they anchor the roofline, they are not
+measurements.  A device kind missing from the table is an error.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict, Tuple
 
+from repro.kernels.diffusion.kernel import VMEM_LIMIT_BYTES
+
 __all__ = [
     "HwSpec",
     "PLATFORM_SPECS",
+    "hw_spec",
     "KernelCost",
     "frontier_round_cost",
     "gather_spmm_cost",
@@ -35,20 +39,35 @@ class HwSpec:
     """Nominal hardware envelope used to anchor the roofline."""
 
     name: str
-    peak_flops: float  # f32 (cpu/gpu) / bf16-MXU (tpu) peak, flop/s
-    mem_bw: float  # main-memory bandwidth, bytes/s (HBM on tpu/gpu)
+    peak_flops: float  # flop/s at the precision named in ``source``
+    mem_bw: float  # main-memory bandwidth, bytes/s (HBM on tpu)
     vmem_budget: int  # fast-memory budget for kernel operands, bytes
+    source: str
 
 
 PLATFORM_SPECS: Dict[str, HwSpec] = {
-    # TPU v5e datasheet: 197 TFLOP/s bf16, 819 GB/s HBM, 128 MiB VMEM —
-    # budget leaves headroom for the compiler's own buffers.
-    "tpu": HwSpec("tpu-v5e", 197e12, 819e9, 64 * 2**20),
-    # A100-class card: 19.5 TFLOP/s f32, 1.56 TB/s HBM2e.
-    "gpu": HwSpec("gpu-a100", 19.5e12, 1.555e12, 48 * 2**20),
-    # a few AVX2 cores — nominal, the CPU path is the jnp oracle anyway.
-    "cpu": HwSpec("cpu-host", 2e11, 4e10, 32 * 2**20),
+    "TPU v5 lite": HwSpec(
+        "tpu-v5e", 197e12, 819e9, VMEM_LIMIT_BYTES,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+        "16 GB HBM at 819 GB/s. The kernels run f32 at HIGHEST "
+        "precision, so their compute roof is below the bf16 peak. VMEM "
+        "budget: the vmem_limit_bytes the kernels compile under."),
+    # the CPU runs the jnp oracle, never the kernels: nominal numbers
+    # that keep the CPU tests' sweep and roofline code paths exercised
+    "cpu": HwSpec("cpu-host", 2e11, 4e10, 32 * 2**20,
+                  source="nominal, not a datasheet"),
 }
+
+
+def hw_spec(device_kind: str) -> HwSpec:
+    """The table entry for ``device_kind``; an unknown kind raises."""
+    try:
+        return PLATFORM_SPECS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak table entry for device kind {device_kind!r}; add "
+            f"one with its source (known: {sorted(PLATFORM_SPECS)})"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)

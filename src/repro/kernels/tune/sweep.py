@@ -27,10 +27,10 @@ import numpy as np
 
 from . import records
 from .model import (
-    PLATFORM_SPECS,
     dma_compute_ratio,
     frontier_round_cost,
     gather_spmm_cost,
+    hw_spec,
     ideal_time_s,
     roofline_fraction,
     vmem_bytes,
@@ -105,7 +105,7 @@ def run_sweep(
         )
     if platform is None:
         platform = jax.default_backend()
-    spec = PLATFORM_SPECS.get(platform, PLATFORM_SPECS["cpu"])
+    spec = hw_spec(jax.devices()[0].device_kind)
     pallas_timing = platform == "tpu"
     rows = []
     for bs in bs_list:

@@ -388,6 +388,9 @@ def _rank_sequential(args):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:]
     if argv and argv[0] == "rank":
         return rank_main(argv[1:])

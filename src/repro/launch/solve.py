@@ -70,8 +70,10 @@ def main(argv=None):
         args.method = "simulator"
 
     import repro
+    from repro.compile_cache import enable_compile_cache
     from repro.core import power_law_graph, webgraph_like
 
+    enable_compile_cache()
     if args.graph_file is not None:
         store = repro.GraphStore.from_edge_file(args.graph_file,
                                                 weighted=args.weighted)

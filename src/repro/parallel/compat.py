@@ -1,24 +1,13 @@
-"""Version-tolerant shims over moving JAX APIs.
+"""Thin spellings of the JAX sharding APIs the repo uses (JAX 0.9).
 
-The repo targets the baked-in toolchain (jax 0.4.x at the time of
-writing) but keeps working as call sites migrate:
-
-* ``shard_map`` — lives at ``jax.experimental.shard_map.shard_map`` on
-  0.4.x (kwarg ``check_rep``) and at ``jax.shard_map`` on newer releases
-  (kwarg ``check_vma``).  :func:`shard_map` accepts either spelling of
-  the replication-check kwarg and forwards whichever the installed
-  version understands.
-* ``make_mesh`` — newer JAX grew an ``axis_types=`` kwarg (and the
-  ``jax.sharding.AxisType`` enum).  :func:`make_mesh` forwards it when
-  supported and silently drops it otherwise (0.4.x meshes are always
-  "auto" in the relevant sense).
-
-Import from here instead of touching ``jax.shard_map`` directly — the
-bare attribute access raises ``AttributeError`` on 0.4.x.
+* :func:`shard_map` — ``jax.shard_map`` with ``mesh``/``in_specs``/
+  ``out_specs`` passed positionally as well as by name.
+* :func:`make_mesh` / :func:`mesh_axis_types_kw` — meshes with every
+  axis in ``AxisType.Auto`` (sharding propagation, no explicit axes).
+* :func:`cost_analysis_dict` — ``compiled.cost_analysis()`` with a
+  missing analysis as ``{}``.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 
@@ -27,53 +16,19 @@ __all__ = ["shard_map", "make_mesh", "mesh_axis_types_kw",
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict across JAX versions.
-
-    0.4.x returns a list with one dict per partition; newer JAX returns
-    the dict directly.  Missing analysis normalizes to ``{}``.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()``; a missing analysis is ``{}``."""
+    return compiled.cost_analysis() or {}
 
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = set(inspect.signature(_shard_map).parameters)
-_CHECK_KW = "check_vma" if "check_vma" in _SM_PARAMS else (
-    "check_rep" if "check_rep" in _SM_PARAMS else None)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None, check_rep=None):
-    """``jax.shard_map`` across JAX versions.
-
-    ``check_vma``/``check_rep`` are the same switch under two names;
-    pass either (or neither).
-    """
-    kw = {}
-    flag = check_vma if check_vma is not None else check_rep
-    if flag is not None and _CHECK_KW is not None:
-        kw[_CHECK_KW] = flag
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-_MM_PARAMS = set(inspect.signature(jax.make_mesh).parameters)
+def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def mesh_axis_types_kw(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,)*n}`` when this JAX supports it, else {}."""
-    if "axis_types" in _MM_PARAMS and hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    """``{"axis_types": (Auto,) * n_axes}`` for ``Mesh``/``make_mesh``."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_mesh(axis_shapes, axis_names, **kwargs):
-    """``jax.make_mesh`` that tolerates ``axis_types=`` on old versions."""
-    if "axis_types" in kwargs and "axis_types" not in _MM_PARAMS:
-        kwargs.pop("axis_types")
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)

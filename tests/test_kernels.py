@@ -304,6 +304,34 @@ def test_gather_spmm_depth_exceeds_visits():
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("depth", [2, 4])
+def test_dma_kernels_trace_under_x64_with_f32_data(depth):
+    """The DMA rings index their slot with an int32 ``rem`` even when
+    ``jax_enable_x64`` widens Python ints; f32 data stays f32 and the
+    result is bit-identical to the same call without x64."""
+    m, f, w, t = _frontier_fixture()
+
+    def both():
+        fr = frontier_round_bsr(
+            m, jnp.asarray(f), jnp.asarray(w), jnp.float32(t),
+            backend="pallas", interpret=True, buffer_depth=depth)
+        pool = np.asarray(m.blocks)
+        order = np.arange(m.n_blocks, dtype=np.int32)
+        x = np.asarray(f).reshape(m.n_row_blocks, m.bs, -1)
+        gs = bsr_gather_spmm_pallas(
+            jnp.asarray(pool), jnp.asarray(order), m.block_row,
+            m.block_col, jnp.asarray(x), m.n_row_blocks, bs=m.bs,
+            interpret=True, buffer_depth=depth)
+        return [np.asarray(a) for a in (*fr, gs)]
+
+    plain = both()
+    with jax.enable_x64(True):
+        wide = both()
+    for a, b in zip(plain, wide):
+        assert a.dtype == b.dtype == np.float32
+        assert np.array_equal(a, b)
+
+
 def test_frontier_round_rejects_bad_depth():
     m, f, w, t = _frontier_fixture(n=100, c=1)
     with pytest.raises(ValueError):

@@ -41,7 +41,8 @@ from repro.kernels.tune import (
     vmem_bytes,
     vmem_ok,
 )
-from repro.kernels.tune.model import PLATFORM_SPECS
+from repro.kernels.diffusion.kernel import VMEM_LIMIT_BYTES
+from repro.kernels.tune.model import PLATFORM_SPECS, hw_spec
 
 from benchmarks import perf_gate
 
@@ -132,11 +133,18 @@ def test_resolved_config_precedence(tune_dir):
 # model / sweep
 # --------------------------------------------------------------------------- #
 def test_vmem_feasibility_model():
-    spec = PLATFORM_SPECS["tpu"]
+    spec = PLATFORM_SPECS["TPU v5 lite"]
+    assert spec.vmem_budget == VMEM_LIMIT_BYTES
     assert vmem_ok(128, 1, 2, spec)
-    # a tile ring this deep cannot fit the 64 MiB budget
+    # a tile ring this deep cannot fit the kernels' VMEM limit
     assert not vmem_ok(2048, 64, 8, spec)
     assert vmem_bytes(128, 1, 4) > vmem_bytes(128, 1, 2)
+
+
+def test_unknown_device_kind_has_no_default_spec():
+    assert hw_spec("cpu") is PLATFORM_SPECS["cpu"]
+    with pytest.raises(KeyError, match="no peak table entry"):
+        hw_spec("TPU v99")
 
 
 def test_sweep_skips_infeasible_and_persists(tune_dir):
