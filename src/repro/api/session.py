@@ -35,6 +35,7 @@ from typing import Iterator, List, Optional, Tuple
 import numpy as np
 
 from repro.kernels.tune import resolved_config
+from repro.obs import span
 
 from .options import SolverOptions
 from .problem import Problem
@@ -344,6 +345,22 @@ class _SegmentSumDriver:
                 np.asarray(ops)[:c], int(rounds), res_cols, stats)
 
 
+def _count_add(count, d):
+    """Add ``d`` (an int, 0 <= d < 2**32) to the exact tally ``count``:
+    ``[high, low]`` uint32 words of ``high * 2**32 + low``.  An int32
+    counter would wrap within one long solve (about 2**31 edge pushes)."""
+    import jax.numpy as jnp
+
+    low = count[1] + d.astype(jnp.uint32)
+    return jnp.stack([count[0] + (low < count[1]).astype(jnp.uint32), low])
+
+
+def _count_value(count) -> int:
+    """The Python int an exact tally (:func:`_count_add`) holds."""
+    high, low = (int(v) for v in np.asarray(count))
+    return high << 32 | low
+
+
 _BSR_ADVANCE: dict = {}
 
 
@@ -353,6 +370,11 @@ def _bsr_advance():
     The BSR operand, the tolerance and the round limit are arguments, so
     every resumed ``advance`` (one per ``trace_every`` rounds) replays
     one compiled program instead of tracing and compiling a new loop.
+    The loop state is ``(f, |f|_1, h, t, ops, rounds, armed)``: ``ops``
+    counts the §2.3 edge pushes and ``armed`` the tiles the kernel's
+    column predicate admitted, both exact tallies (:func:`_count_add`).
+    The stages of a round carry ``frontier.*`` named scopes, so a device
+    trace names the stage each operation belongs to.
     """
     if _BSR_ADVANCE:
         return _BSR_ADVANCE["fn"]
@@ -366,26 +388,36 @@ def _bsr_advance():
     @functools.partial(jax.jit, static_argnames=(
         "gamma", "backend", "interpret", "buffer_depth",
         "occupancy_threshold"))
-    def advance(state, m, w, out_deg, dang, tol, round_limit, *, gamma,
-                backend, interpret, buffer_depth, occupancy_threshold):
+    def advance(state, m, w, out_deg, dang, col_tiles, tol, round_limit, *,
+                gamma, backend, interpret, buffer_depth,
+                occupancy_threshold):
         def cond(state):
-            f, res, h, t, ops, rounds = state
+            f, res, h, t, ops, rounds, armed = state
             return (res > tol) & (rounds < round_limit)
 
         def body(state):
-            f, _res, h, t, ops, rounds = state
+            f, _res, h, t, ops, rounds, armed = state
             f_new, sent, res = frontier_round_bsr(
                 m, f, w, t, backend=backend, interpret=interpret,
                 buffer_depth=buffer_depth,
                 occupancy_threshold=occupancy_threshold)
-            # the op's threshold predicate is authoritative (the pallas
-            # backend folds t into the weights); sel follows the sent fluid
-            sel = sent != 0
-            dops = jnp.sum(jnp.where(sel, out_deg, 0))
-            dops = dops + jnp.sum((sel & dang).astype(jnp.int32))
-            t_new = jnp.where(jnp.any(sel), t, t / gamma)
-            return (f_new, res, h + sent, t_new,
-                    ops + dops.astype(ops.dtype), rounds + 1)
+            with jax.named_scope("frontier.history"):
+                h = h + sent
+            with jax.named_scope("frontier.count"):
+                # the op's threshold predicate is authoritative (the
+                # pallas backend folds t into the weights); sel follows
+                # the sent fluid
+                sel = sent != 0
+                dops = jnp.sum(jnp.where(sel, out_deg, 0))
+                dops = dops + jnp.sum((sel & dang).astype(jnp.int32))
+                # the kernel arms a block column exactly when it holds a
+                # sent node, and then reads every tile of that column
+                col_armed = jnp.any(sel.reshape(col_tiles.shape[0], -1),
+                                    axis=1)
+                darmed = jnp.sum(jnp.where(col_armed, col_tiles, 0))
+                t_new = jnp.where(jnp.any(sel), t, t / gamma)
+            return (f_new, res, h, t_new, _count_add(ops, dops),
+                    rounds + 1, _count_add(armed, darmed))
 
         return jax.lax.while_loop(cond, body, state)
 
@@ -413,7 +445,11 @@ class _BsrFrontierDriver:
         )
         # the store's cached BSR view: graph deltas patch dirty tiles
         # in place, so a post-update rebuild re-uploads — not re-tiles
-        self.m = problem.graph.bsr(bs=bs).to_device()
+        tiles = problem.graph.bsr(bs=bs)
+        self.m = tiles.to_device()
+        # tiles per block column, for the armed-tile tally
+        self.col_tiles = jnp.asarray(np.bincount(
+            tiles.block_col, minlength=tiles.n_row_blocks), dtype=jnp.int32)
         n_pad = self.m.n_row_blocks * bs
         dt = self.m.blocks.dtype
         # device edge list for the device-resident warm start (P·H via
@@ -446,8 +482,8 @@ class _BsrFrontierDriver:
         if h_nodes is not None:
             h = h.at[: self.n].set(jnp.asarray(h_nodes, dtype=self._dt))
         t = jnp.abs(f * self.w).max() * 2.0
-        self._state = (f, jnp.abs(f).sum(), h, t,
-                       jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+        self._state = (f, jnp.abs(f).sum(), h, t, jnp.zeros(2, jnp.uint32),
+                       jnp.zeros((), jnp.int32), jnp.zeros(2, jnp.uint32))
 
     def warm_seed(self, b_new: np.ndarray) -> float:
         """Device-resident warm start over the padded state (see
@@ -455,7 +491,7 @@ class _BsrFrontierDriver:
         import jax
         import jax.numpy as jnp
 
-        f_old, _res, h, _t, _ops, _rounds = self._state
+        h = self._state[2]
         h_n = h[: self.n]
         b = jnp.asarray(b_new, dtype=self._dt)
         ph = jax.ops.segment_sum(h_n[self._src_d] * self._wgt_d,
@@ -464,29 +500,43 @@ class _BsrFrontierDriver:
         f = jnp.zeros(self._n_pad, dtype=self._dt).at[: self.n].set(f_n)
         res = jnp.abs(f).sum()
         t = jnp.abs(f * self.w).max() * 2.0
-        self._state = (f, res, h, t, jnp.zeros((), jnp.int32),
-                       jnp.zeros((), jnp.int32))
+        self._state = (f, res, h, t, jnp.zeros(2, jnp.uint32),
+                       jnp.zeros((), jnp.int32), jnp.zeros(2, jnp.uint32))
         return float(res)
 
     def advance(self, tol: float, round_limit: int) -> None:
-        self._state = _bsr_advance()(
-            self._state, self.m, self.w, self.out_deg, self.dang, tol,
-            round_limit, gamma=self.gamma, backend=self.op_backend,
-            interpret=self.interpret or None,
-            buffer_depth=self.buffer_depth,
-            occupancy_threshold=self.occupancy_threshold)
+        # the span is the host's dispatch: argument flattening, the
+        # static-argument hash, the cache lookup and the enqueue
+        with span("dispatch"):
+            self._state = _bsr_advance()(
+                self._state, self.m, self.w, self.out_deg, self.dang,
+                self.col_tiles, tol, round_limit, gamma=self.gamma,
+                backend=self.op_backend, interpret=self.interpret or None,
+                buffer_depth=self.buffer_depth,
+                occupancy_threshold=self.occupancy_threshold)
 
     def x(self) -> np.ndarray:
         return np.asarray(self._state[2][: self.n], dtype=np.float64)
 
+    # each read waits for the device: the first after a dispatch for the
+    # whole grain, the others only for their transfer
     def residual(self) -> float:
-        return float(self._state[1])
+        with span("readback.residual"):
+            return float(self._state[1])
 
     def ops(self) -> int:
-        return int(self._state[4])
+        with span("readback.ops"):
+            return _count_value(self._state[4])
 
     def rounds(self) -> int:
-        return int(self._state[5])
+        with span("readback.rounds"):
+            return int(self._state[5])
+
+    def armed_tiles(self) -> int:
+        """Tiles the kernel's column predicate admitted, summed over the
+        rounds of the current phase."""
+        with span("readback.armed_tiles"):
+            return _count_value(self._state[6])
 
     def exhausted(self) -> bool:
         return False
@@ -508,10 +558,10 @@ class _BsrFrontierDriver:
         t = np.asarray(t, dtype=np.float64).reshape(-1)
         if t.shape != (1,):
             return  # cross-width checkpoint: keep the re-derived T
-        f, res, h, t_old, ops, rounds = self._state
+        f, res, h, t_old, *counters = self._state
         self._state = (f, res, h,
                        jnp.asarray(t[0], dtype=t_old.dtype).reshape(()),
-                       ops, rounds)
+                       *counters)
 
 
 # --------------------------------------------------------------------------- #
@@ -914,6 +964,7 @@ class SolverSession:
         # ``lifetime_ops`` is THE one rule recovery-cost consumers sum
         self._ops_banked = 0
         self._rounds_banked = 0
+        self._armed_banked = 0
 
     # ---- state views ------------------------------------------------------
     @property
@@ -935,6 +986,21 @@ class SolverSession:
         return self._driver.rounds()
 
     @property
+    def n_armed_tiles(self) -> Optional[int]:
+        """Tiles the fused frontier kernel's column predicate admitted in
+        the current solve phase, summed over its rounds: the tiles a round
+        needs read.  None for a backend without a tile pool.  One device
+        read, made only when asked."""
+        armed = getattr(self._driver, "armed_tiles", None)
+        return None if armed is None else armed()
+
+    @property
+    def lifetime_armed_tiles(self) -> Optional[int]:
+        """:attr:`n_armed_tiles` across the session's whole life."""
+        armed = self.n_armed_tiles
+        return None if armed is None else self._armed_banked + armed
+
+    @property
     def lifetime_ops(self) -> int:
         """Edge pushes charged across the session's whole life (§2.3):
         every solve phase since construction or restore, including work
@@ -950,6 +1016,7 @@ class SolverSession:
         call ONLY immediately before a re-seed resets them."""
         self._ops_banked += self._driver.ops()
         self._rounds_banked += self._driver.rounds()
+        self._armed_banked += self.n_armed_tiles or 0
 
     def _tol(self, until: Optional[float]) -> float:
         te = until if until is not None else self.problem.target_error
@@ -996,12 +1063,14 @@ class SolverSession:
             if chaos is not None:
                 chaos.before_grain(self)
                 d = self._driver  # chaos may have rebuilt the driver
-            if isinstance(d, _EngineDriver):
-                d.advance(tol, cap)
-            else:
-                d.advance(tol, min(d.rounds() + self.options.trace_every,
-                                   cap))
-            yield RoundReport(d.rounds(), d.residual(), d.ops())
+            with span("grain"):
+                if isinstance(d, _EngineDriver):
+                    d.advance(tol, cap)
+                else:
+                    d.advance(tol, min(
+                        d.rounds() + self.options.trace_every, cap))
+                report = RoundReport(d.rounds(), d.residual(), d.ops())
+            yield report
 
     def solve(self, until: Optional[float] = None,
               max_rounds: Optional[int] = None,
@@ -1198,6 +1267,8 @@ class SolverSession:
             "rounds": d.rounds(),
             "lifetime_ops": self.lifetime_ops,
             "lifetime_rounds": self.lifetime_rounds,
+            "armed_tiles": self.n_armed_tiles,
+            "lifetime_armed_tiles": self.lifetime_armed_tiles,
             "residual": d.residual(),
             "move_log": [list(m) for m in d.move_log()],
         }
@@ -1313,6 +1384,8 @@ class SolverSession:
                 "rounds": extra.get("rounds", 0),
                 "lifetime_ops": extra.get("lifetime_ops",
                                           extra.get("ops", 0)),
+                "armed_tiles": extra.get("armed_tiles"),
+                "lifetime_armed_tiles": extra.get("lifetime_armed_tiles"),
                 "move_log": [tuple(m) for m in extra.get("move_log", [])],
                 "rejected": rejected,
             }

@@ -150,50 +150,59 @@ def frontier_round_bsr(
     c = f2.shape[1]
     if backend in (None, "auto"):
         backend = "pallas" if _on_tpu() else "block"
-    if backend == "pallas":
-        # the kernel folds the threshold into the weights (wt = w/t, select
-        # when |f|*wt > 1); the wrapper MUST use the identical rounded
-        # predicate or a boundary node could be "sent" by one side and
-        # "kept" by the other, double-counting or losing its fluid.
-        wt_flat = (w / t).astype(f2.dtype)
-        sel = jnp.abs(f2) * wt_flat[:, None] > 1.0
-    else:
-        sel = jnp.abs(f2) * w[:, None] > t
-    blk = sel.reshape(-1, m.bs * c)
-    if occupancy_threshold > 0.0:
-        frac = jnp.mean(blk.astype(f2.dtype), axis=1)
-        col_active = (frac > occupancy_threshold).astype(jnp.int32)
-        # only nodes in armed columns fire; the rest keep their fluid.
-        sel = jnp.logical_and(
-            sel, (col_active != 0).repeat(m.bs)[:, None]
-        )
-    else:
-        col_active = jnp.any(blk, axis=1).astype(jnp.int32)
-    sent = jnp.where(sel, f2, jnp.zeros_like(f2))
+    with jax.named_scope("frontier.select"):
+        if backend == "pallas":
+            # the kernel folds the threshold into the weights (wt = w/t,
+            # select when |f|*wt > 1); the wrapper MUST use the identical
+            # rounded predicate or a boundary node could be "sent" by one
+            # side and "kept" by the other, double-counting or losing its
+            # fluid.
+            wt_flat = (w / t).astype(f2.dtype)
+            sel = jnp.abs(f2) * wt_flat[:, None] > 1.0
+        else:
+            sel = jnp.abs(f2) * w[:, None] > t
+        blk = sel.reshape(-1, m.bs * c)
+        if occupancy_threshold > 0.0:
+            frac = jnp.mean(blk.astype(f2.dtype), axis=1)
+            col_active = (frac > occupancy_threshold).astype(jnp.int32)
+            # only nodes in armed columns fire; the rest keep their fluid.
+            sel = jnp.logical_and(
+                sel, (col_active != 0).repeat(m.bs)[:, None]
+            )
+        else:
+            col_active = jnp.any(blk, axis=1).astype(jnp.int32)
+        sent = jnp.where(sel, f2, jnp.zeros_like(f2))
     if backend == "block":
-        xt = sent.reshape(-1, m.bs, c)
-        delta = bsr_spmm_ref(
-            m.blocks, m.block_row, m.block_col, xt, m.n_row_blocks
-        )
-        f_new = (f2 - sent) + delta.reshape(f2.shape)
-        res = jnp.sum(jnp.abs(f_new))
+        with jax.named_scope("frontier.kernel"):
+            xt = sent.reshape(-1, m.bs, c)
+            delta = bsr_spmm_ref(
+                m.blocks, m.block_row, m.block_col, xt, m.n_row_blocks
+            )
+        with jax.named_scope("frontier.kept"):
+            f_new = (f2 - sent) + delta.reshape(f2.shape)
+        with jax.named_scope("frontier.residual"):
+            res = jnp.sum(jnp.abs(f_new))
     elif backend == "pallas":
         if interpret is None:
             interpret = not _on_tpu()
-        ft = f2.reshape(-1, m.bs, c)
-        wt = wt_flat.reshape(-1, m.bs, 1)
-        out, row_l1 = frontier_round_bsr_pallas(
-            m.blocks.astype(f2.dtype), m.block_row, m.block_col, col_active,
-            ft, wt, m.n_row_blocks, bs=m.bs, interpret=interpret,
-            buffer_depth=buffer_depth,
-        )
+        with jax.named_scope("frontier.kernel"):
+            ft = f2.reshape(-1, m.bs, c)
+            wt = wt_flat.reshape(-1, m.bs, 1)
+            out, row_l1 = frontier_round_bsr_pallas(
+                m.blocks.astype(f2.dtype), m.block_row, m.block_col,
+                col_active, ft, wt, m.n_row_blocks, bs=m.bs,
+                interpret=interpret, buffer_depth=buffer_depth,
+            )
         # rows owning no block never get their output tile initialised:
         # substitute the kept fluid (F - sent) and its |·|_1 there.
-        keep = (f2 - sent).reshape(-1, m.bs, c)
         occ = m.row_occupied
-        f_new = jnp.where(occ[:, None, None], out, keep).reshape(f2.shape)
-        keep_l1 = jnp.sum(jnp.abs(keep), axis=(1, 2))
-        res = jnp.sum(jnp.where(occ, row_l1[:, 0, 0], keep_l1))
+        with jax.named_scope("frontier.kept"):
+            keep = (f2 - sent).reshape(-1, m.bs, c)
+            f_new = jnp.where(occ[:, None, None], out, keep).reshape(
+                f2.shape)
+        with jax.named_scope("frontier.residual"):
+            keep_l1 = jnp.sum(jnp.abs(keep), axis=(1, 2))
+            res = jnp.sum(jnp.where(occ, row_l1[:, 0, 0], keep_l1))
     else:
         raise ValueError(f"unknown frontier backend {backend!r}")
     if squeeze:

@@ -124,3 +124,38 @@ def test_frontier_round_at_2_21_fits_one_v5e(one_chip):
     assert need < V5E_HBM_BYTES, (
         f"arguments {mem.argument_size_in_bytes} + temporaries "
         f"{mem.temp_size_in_bytes} bytes exceed one v5e")
+
+
+def test_frontier_grain_at_2_21_fits_one_v5e(one_chip):
+    """The session's whole jitted grain at N = 2^21: the loop of fused
+    rounds with its state, exact counters included.  The kernel keeps the
+    name the benchmark's kernel reader matches, and each stage of a round
+    carries its ``frontier.*`` named scope in the op metadata."""
+    from repro.api.session import _bsr_advance
+
+    bs, n = 128, 2**21
+    nrb = n // bs
+    n_blocks = 5 * nrb
+    f32, i32, u32 = jnp.float32, jnp.int32, jnp.uint32
+
+    def spec(shape, dtype):
+        return _spec(shape, dtype, one_chip)
+
+    m = BsrMatrix.from_arrays(
+        spec((n_blocks, bs, bs), f32), spec((n_blocks,), i32),
+        spec((n_blocks,), i32), spec((nrb,), jnp.bool_), nrb, bs)
+    state = (spec((n,), f32), spec((), f32), spec((n,), f32), spec((), f32),
+             spec((2,), u32), spec((), i32), spec((2,), u32))
+    compiled = _bsr_advance().lower(
+        state, m, spec((n,), f32), spec((n,), i32), spec((n,), jnp.bool_),
+        spec((nrb,), i32), spec((), f32), spec((), i32), gamma=1.2,
+        backend="pallas", interpret=False, buffer_depth=1,
+        occupancy_threshold=0.0).compile()
+    text = compiled.as_text()
+    assert "%frontier_round_bsr_pallas" in text
+    for stage in ("select", "kernel", "kept", "residual", "history",
+                  "count"):
+        assert f"/frontier.{stage}/" in text, stage
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < V5E_HBM_BYTES
