@@ -45,6 +45,7 @@ from repro.kernels.diffusion import (
     BsrMatrix,
     bsr_spmm,
     bsr_spmm_ref,
+    compact_visits,
     frontier_round_bsr,
     frontier_round_bsr_pallas,
     frontier_round_ref,
@@ -195,9 +196,9 @@ def frontier_sweep(
               "intra_frac=0.92, span_hosts=2)",
         note=(
             "pallas_skip_us is the occupancy-restricted BSR path: on TPU "
-            "the fused kernel skips inactive tiles in-kernel via the "
-            "scalar-prefetched col_active map; off-TPU the same tile "
-            "subset runs through the module-level jitted jnp block oracle "
+            "the fused kernel's grid runs over the visit list of the "
+            "active columns' tiles (compaction untimed); off-TPU the same "
+            "tile subset runs through the module-level jitted jnp block oracle "
             "(cache keyed on shape; compacted lists pow2-padded).  Off-TPU "
             "the oracle has no buffer_depth, so depth rows share the "
             "oracle timing; on TPU each depth times the real pipeline.  "
@@ -256,13 +257,13 @@ def frontier_sweep(
                         col_active = np.zeros(m.n_row_blocks, np.int32)
                         col_active[hot] = 1
                         caj = jnp.asarray(col_active)
+                        visits = compact_visits(m, caj)[:3]
                         ft = fj.reshape(-1, bs, c)
                         wt = (wj / t).reshape(-1, bs, 1)
                         skip_us = timeit(
                             lambda ft_, wt_: frontier_round_bsr_pallas(
-                                m.blocks, m.block_row, m.block_col, caj,
-                                ft_, wt_, m.n_row_blocks, bs=bs,
-                                buffer_depth=depth),
+                                m.blocks, *visits, caj, ft_, wt_,
+                                m.n_row_blocks, bs=bs, buffer_depth=depth),
                             ft, wt, iters=it)
                     elif depth == depths[0]:
                         skip_us = timeit(

@@ -371,8 +371,8 @@ def _bsr_advance():
     every resumed ``advance`` (one per ``trace_every`` rounds) replays
     one compiled program instead of tracing and compiling a new loop.
     The loop state is ``(f, |f|_1, h, t, ops, rounds, armed)``: ``ops``
-    counts the §2.3 edge pushes and ``armed`` the tiles the kernel's
-    column predicate admitted, both exact tallies (:func:`_count_add`).
+    counts the §2.3 edge pushes and ``armed`` the tiles the fused kernel
+    visits (its grid steps), both exact tallies (:func:`_count_add`).
     The stages of a round carry ``frontier.*`` named scopes, so a device
     trace names the stage each operation belongs to.
     """
@@ -411,7 +411,7 @@ def _bsr_advance():
                 dops = jnp.sum(jnp.where(sel, out_deg, 0))
                 dops = dops + jnp.sum((sel & dang).astype(jnp.int32))
                 # the kernel arms a block column exactly when it holds a
-                # sent node, and then reads every tile of that column
+                # sent node, and then visits every tile of that column
                 col_armed = jnp.any(sel.reshape(col_tiles.shape[0], -1),
                                     axis=1)
                 darmed = jnp.sum(jnp.where(col_armed, col_tiles, 0))
@@ -533,8 +533,8 @@ class _BsrFrontierDriver:
             return int(self._state[5])
 
     def armed_tiles(self) -> int:
-        """Tiles the kernel's column predicate admitted, summed over the
-        rounds of the current phase."""
+        """Tiles the fused kernel visited, summed over the rounds of the
+        current phase."""
         with span("readback.armed_tiles"):
             return _count_value(self._state[6])
 
@@ -987,10 +987,10 @@ class SolverSession:
 
     @property
     def n_armed_tiles(self) -> Optional[int]:
-        """Tiles the fused frontier kernel's column predicate admitted in
-        the current solve phase, summed over its rounds: the tiles a round
-        needs read.  None for a backend without a tile pool.  One device
-        read, made only when asked."""
+        """Tiles the fused frontier kernel visited in the current solve
+        phase, summed over its rounds: its grid steps, the tiles of the
+        block columns holding a sent node.  None for a backend without a
+        tile pool.  One device read, made only when asked."""
         armed = getattr(self._driver, "armed_tiles", None)
         return None if armed is None else armed()
 

@@ -1,6 +1,7 @@
 from .ops import (  # noqa: F401
     BsrMatrix,
     bsr_spmm,
+    compact_visits,
     frontier_round_bsr,
     prepare_bsr,
 )

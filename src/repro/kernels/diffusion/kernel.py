@@ -19,6 +19,14 @@ diffusion vectors (e.g. personalized-PageRank columns) share one sweep of
 the sparse structure; ``C = 1`` is the paper's case but wider C raises
 arithmetic intensity from O(1) to O(C) per weight byte.
 
+The fused frontier round (``frontier_round_bsr_pallas``) runs the same
+revisiting-output pattern over a per-round *visit list* instead of the
+whole tile pool: one grid step per tile of an armed block column (one
+holding fluid above threshold), the grid's length being the round's
+visit count, a traced value.  Tiles of unarmed columns cost no step, no
+byte and no MXU issue slot; rows without a visit take the kept fluid in
+the ops.py wrapper.
+
 DMA pipelining (``buffer_depth``): the tile pool is the dominant byte
 stream (``bs*bs`` weights vs ``bs*C`` fluid per step, and C is small).
 With ``buffer_depth == 1`` the tile fetch rides Pallas's automatic
@@ -26,11 +34,11 @@ double-buffered BlockSpec pipeline.  With ``buffer_depth >= 2`` the tile
 operand stays in HBM (``memory_space=ANY``) and the kernel rotates manual
 async copies through a ``[depth, bs, bs]`` VMEM ring: step ``i`` computes
 out of slot ``i % depth`` while the DMAs for steps ``i+1 .. i+depth-1``
-are already in flight.  The occupancy skip composes with the ring — a
-block column with no fluid above threshold never has its DMA *started*,
-so inactive tiles cost neither bytes nor MXU issue slots.  Both paths
-execute the identical accumulation order, so results are bit-identical
-across depths (test-enforced).
+are already in flight.  In the frontier round every step is a visit, so
+the ring starts and waits one copy a step, and only its warm-up and its
+last ``depth`` refills stop at the visit count.  Both paths execute the
+identical accumulation order, so results are bit-identical across depths
+(test-enforced).
 """
 from __future__ import annotations
 
@@ -260,36 +268,53 @@ def bsr_gather_spmm_pallas(
 
 
 # --------------------------------------------------------------------------- #
-# fused frontier round: threshold masking of the sent fluid, the block-column
-# occupancy skip, and the per-block-row residual reduction all live inside the
-# kernel, so one grid sweep turns F into F' = F - sent + P @ sent and emits the
-# per-row |F'|_1 partial sums the solver's stopping rule needs.
+# fused frontier round: threshold masking of the sent fluid and the per-block-
+# row residual reduction live inside the kernel, so one grid sweep over the
+# round's visit list (the tiles of the armed block columns) turns F into
+# F' = F - sent + P @ sent on every visited row and emits the per-row |F'|_1
+# partial sums the solver's stopping rule needs.
 # --------------------------------------------------------------------------- #
-def _frontier_kernel(block_row_ref, block_col_ref, col_active_ref,
-                     blocks_ref, f_col_ref, wt_col_ref, f_row_ref, wt_row_ref,
-                     o_ref, l1_ref, *, n_blocks: int):
-    """Grid step i (blocks sorted by block_row):
+# A visit packs its tile's block row and block column into one int32,
+# ``row << VISIT_ROW_SHIFT | col``, so the visit list takes no more scalar
+# memory than the block_row/block_col pair it stands in for.
+VISIT_ROW_SHIFT = 16
+_VISIT_COL_MASK = (1 << VISIT_ROW_SHIFT) - 1
+# the packed word is an int32: rows below 2**15, columns below 2**16
+MAX_ROW_BLOCKS = 1 << (31 - VISIT_ROW_SHIFT)
+
+
+def _visit_row(rc):
+    return rc >> VISIT_ROW_SHIFT
+
+
+def _visit_col(rc):
+    return rc & _VISIT_COL_MASK
+
+
+def _frontier_step(visit_rc_ref, col_active_ref, tile, f_col_ref,
+                   wt_col_ref, f_row_ref, wt_row_ref, o_ref, l1_ref):
+    """Grid step i, on visit i (visits sorted by block row):
 
     * first visit of a row: seed o with the row's kept fluid
       ``where(sel, 0, f)`` (the un-diffused residual) where
       ``sel = (|f| * wt > 1) & (col_active[row] != 0)`` — a node only counts
       as "sent" if its own block column is armed this round, so deferred
       columns (occupancy threshold) keep their fluid intact,
-    * active column: accumulate ``blocks[i] @ sent(col)`` where
+    * every visit: accumulate ``tile @ sent(col)`` where
       ``sent = where(|f| * wt > 1, f, 0)`` is recomputed in-register —
       ``wt = w / T`` folds the threshold into the weights so no scalar
-      operand is needed,
-    * inactive column (no fluid above threshold anywhere in the col block —
-      most tiles late in convergence — or deferred below the occupancy
-      threshold): the matmul is skipped entirely,
+      operand is needed; the visit list holds armed columns' tiles only,
     * last visit of a row: reduce ``|o|_1`` into the per-row residual output.
+
+    ``tile()`` reads the step's tile from wherever the fetch strategy put it.
     """
     i = pl.program_id(0)
-    row = block_row_ref[i]
-    prev_row = block_row_ref[jnp.maximum(i - 1, 0)]
-    next_row = block_row_ref[jnp.minimum(i + 1, n_blocks - 1)]
+    n = pl.num_programs(0)
+    row = _visit_row(visit_rc_ref[i])
+    prev_row = _visit_row(visit_rc_ref[jnp.maximum(i - 1, 0)])
+    next_row = _visit_row(visit_rc_ref[jnp.minimum(i + 1, n - 1)])
     first = jnp.logical_or(i == 0, row != prev_row)
-    last = jnp.logical_or(i == n_blocks - 1, next_row != row)
+    last = jnp.logical_or(i == n - 1, next_row != row)
 
     @pl.when(first)
     def _seed_kept_fluid():
@@ -299,81 +324,60 @@ def _frontier_kernel(block_row_ref, block_col_ref, col_active_ref,
         )
         o_ref[0] = jnp.where(sel, jnp.zeros_like(fr), fr)
 
-    @pl.when(col_active_ref[block_col_ref[i]] != 0)
-    def _push():
-        fc = f_col_ref[0]
-        sent = jnp.where(jnp.abs(fc) * wt_col_ref[0] > 1.0, fc,
-                         jnp.zeros_like(fc))
-        o_ref[0] += _mxu_dot(blocks_ref[0], sent, o_ref.dtype)
+    fc = f_col_ref[0]
+    sent = jnp.where(jnp.abs(fc) * wt_col_ref[0] > 1.0, fc, jnp.zeros_like(fc))
+    o_ref[0] += _mxu_dot(tile(), sent, o_ref.dtype)
 
     @pl.when(last)
     def _row_residual():
         l1_ref[0] = jnp.sum(jnp.abs(o_ref[0])).reshape(1, 1)
 
 
-def _frontier_kernel_dma(block_row_ref, block_col_ref, col_active_ref,
+def _frontier_kernel(visit_tile_ref, visit_rc_ref, col_active_ref,
+                     blocks_ref, f_col_ref, wt_col_ref, f_row_ref, wt_row_ref,
+                     o_ref, l1_ref):
+    """BlockSpec-pipelined step (``buffer_depth == 1``): the automatic
+    pipeline has already brought tile ``visit_tile[i]`` into VMEM."""
+    _frontier_step(visit_rc_ref, col_active_ref, lambda: blocks_ref[0],
+                   f_col_ref, wt_col_ref, f_row_ref, wt_row_ref, o_ref,
+                   l1_ref)
+
+
+def _frontier_kernel_dma(visit_tile_ref, visit_rc_ref, col_active_ref,
                          blocks_hbm_ref, f_col_ref, wt_col_ref, f_row_ref,
                          wt_row_ref, o_ref, l1_ref, buf_ref, sem_ref,
-                         *, n_blocks: int, depth: int):
+                         *, n_tiles: int, depth: int):
     """Manual-DMA twin of :func:`_frontier_kernel` (``buffer_depth >= 2``).
 
-    The occupancy skip gates the *DMA* as well as the matmul: a tile whose
-    block column carries no above-threshold fluid is never copied out of
-    HBM.  Start and wait use the identical predicate, so every started copy
-    is waited exactly once and slot ``j % depth`` is free again before step
-    ``j + depth`` reuses it.
+    Every step is a visit, so every step starts and waits one copy: step
+    ``i`` waits on slot ``i % depth`` and refills it with the tile of visit
+    ``i + depth``.  The warm-up and the refill stop at the round's visit
+    count, so every started copy is waited exactly once.
     """
     i = pl.program_id(0)
-    row = block_row_ref[i]
-    prev_row = block_row_ref[jnp.maximum(i - 1, 0)]
-    next_row = block_row_ref[jnp.minimum(i + 1, n_blocks - 1)]
-    first = jnp.logical_or(i == 0, row != prev_row)
-    last = jnp.logical_or(i == n_blocks - 1, next_row != row)
+    n = pl.num_programs(0)
 
     def tile_dma(slot, step):
         return pltpu.make_async_copy(
-            blocks_hbm_ref.at[step], buf_ref.at[slot], sem_ref.at[slot]
-        )
-
-    def col_armed(step):
-        return col_active_ref[block_col_ref[step]] != 0
+            blocks_hbm_ref.at[visit_tile_ref[step]], buf_ref.at[slot],
+            sem_ref.at[slot])
 
     @pl.when(i == 0)
     def _warmup():
-        for d in range(min(depth, n_blocks)):
-            @pl.when(col_armed(d))
+        for d in range(min(depth, n_tiles)):
+            @pl.when(d < n)
             def _start(d=d):
                 tile_dma(d, d).start()
 
-    @pl.when(first)
-    def _seed_kept_fluid():
-        fr = f_row_ref[0]
-        sel = jnp.logical_and(
-            jnp.abs(fr) * wt_row_ref[0] > 1.0, col_active_ref[row] != 0
-        )
-        o_ref[0] = jnp.where(sel, jnp.zeros_like(fr), fr)
-
     slot = jax.lax.rem(i, jnp.int32(depth))
+    tile_dma(slot, i).wait()
+    _frontier_step(visit_rc_ref, col_active_ref, lambda: buf_ref[slot],
+                   f_col_ref, wt_col_ref, f_row_ref, wt_row_ref, o_ref,
+                   l1_ref)
 
-    @pl.when(col_armed(i))
-    def _push():
-        tile_dma(slot, i).wait()
-        fc = f_col_ref[0]
-        sent = jnp.where(jnp.abs(fc) * wt_col_ref[0] > 1.0, fc,
-                         jnp.zeros_like(fc))
-        o_ref[0] += _mxu_dot(buf_ref[slot], sent, o_ref.dtype)
-
-    # slot is free again (its copy was waited above, or never started);
-    # immediately refill it with the tile this slot serves next.
-    nxt = jnp.minimum(i + depth, n_blocks - 1)
-
-    @pl.when(jnp.logical_and(i + depth < n_blocks, col_armed(nxt)))
+    @pl.when(i + depth < n)
     def _prefetch():
-        tile_dma(slot, nxt).start()
-
-    @pl.when(last)
-    def _row_residual():
-        l1_ref[0] = jnp.sum(jnp.abs(o_ref[0])).reshape(1, 1)
+        tile_dma(slot, i + depth).start()
 
 
 @functools.partial(
@@ -381,9 +385,10 @@ def _frontier_kernel_dma(block_row_ref, block_col_ref, col_active_ref,
     static_argnames=("n_row_blocks", "interpret", "bs", "buffer_depth"),
 )
 def frontier_round_bsr_pallas(
-    blocks: jax.Array,  # [n_blocks, bs, bs] dense tiles of P, row-sorted
-    block_row: jax.Array,  # [n_blocks] int32, sorted ascending
-    block_col: jax.Array,  # [n_blocks] int32
+    blocks: jax.Array,  # [n_tiles, bs, bs] dense tiles of P, row-sorted
+    visit_tile: jax.Array,  # [n_tiles] int32, the round's visits first
+    visit_rc: jax.Array,  # [n_tiles] int32, row << VISIT_ROW_SHIFT | col
+    n_visits: jax.Array,  # int32 scalar, the visit count of the round
     col_active: jax.Array,  # [n_col_blocks] int32 occupancy of the frontier
     f: jax.Array,  # [n_col_blocks, bs, C] residual fluid, tiled
     wt: jax.Array,  # [n_col_blocks, bs, 1] selection weights / threshold
@@ -393,56 +398,78 @@ def frontier_round_bsr_pallas(
     interpret: bool = False,
     buffer_depth: int = 1,
 ) -> tuple[jax.Array, jax.Array]:
-    """One fused frontier round over the BSR structure.
+    """One fused frontier round over the round's visit list.
+
+    The grid has one step per visit: visit ``i`` multiplies tile
+    ``visit_tile[i]``, of block row and column ``visit_rc[i]``, for
+    ``i < n_visits``.  The visits are the tiles of the armed block columns
+    in their row-sorted order (``ops.compact_visits`` builds them), so a
+    round walks the armed tiles only and no step is spent on a column
+    without sent fluid.  Entries from ``n_visits`` on are never read as
+    visits; they must hold valid tile indices, because a round without
+    visits still runs one step, whose output no row keeps.
 
     Returns ``(f_new, row_l1)`` with ``f_new: [n_row_blocks, bs, C]`` holding
-    ``F - sent + P @ sent`` for every *occupied* block row and
+    ``F - sent + P @ sent`` for every *visited* block row and
     ``row_l1: [n_row_blocks, 1, 1]`` its per-row |·|_1 (3-D so that its
     ``(1, 1, 1)`` block spans the array's last two dims, as Mosaic
-    requires of a block that is not (8, 128)-aligned).  Rows that own no block
-    are left uninitialised (garbage) in BOTH outputs by design — the ops.py
-    wrapper substitutes the kept fluid ``F - sent`` there via the
-    row-occupancy map.  The square tiling (n_col_blocks == n_row_blocks)
-    means the f/wt operands serve double duty: indexed by block_col for the
-    sent gather and by block_row for the kept-fluid seeding.
+    requires of a block that is not (8, 128)-aligned).  Rows without a
+    visit are left uninitialised (garbage) in BOTH outputs by design — the
+    ops.py wrapper substitutes the kept fluid ``F - sent`` there via the
+    round's row-visited map.  The square tiling (n_col_blocks ==
+    n_row_blocks) means the f/wt operands serve double duty: indexed by
+    the visit's column for the sent gather and by its row for the
+    kept-fluid seeding.
 
     ``buffer_depth`` selects the tile-fetch strategy: 1 = automatic BlockSpec
-    pipelining, >= 2 = a manual ``depth``-deep async-copy ring whose DMAs are
-    occupancy-gated (see module docstring).  Bit-identical across depths.
+    pipelining, >= 2 = a manual ``depth``-deep async-copy ring over the
+    visits (see module docstring).  Bit-identical across depths.
     """
     if buffer_depth < 1:
         raise ValueError(f"buffer_depth must be >= 1, got {buffer_depth}")
-    n_blocks = blocks.shape[0]
+    if n_row_blocks > MAX_ROW_BLOCKS:
+        raise ValueError(f"{n_row_blocks} block rows do not pack into a "
+                         f"visit word (at most {MAX_ROW_BLOCKS})")
+    n_tiles = blocks.shape[0]
     c = f.shape[-1]
     out_shape = (
         jax.ShapeDtypeStruct((n_row_blocks, bs, c), f.dtype),
         jax.ShapeDtypeStruct((n_row_blocks, 1, 1), f.dtype),
     )
+
+    def by_col(i, vt, rc, ca):
+        return _visit_col(rc[i]), 0, 0
+
+    def by_row(i, vt, rc, ca):
+        return _visit_row(rc[i]), 0, 0
+
     fluid_specs = [
-        pl.BlockSpec((1, bs, c), lambda i, br, bc, ca: (bc[i], 0, 0)),
-        pl.BlockSpec((1, bs, 1), lambda i, br, bc, ca: (bc[i], 0, 0)),
-        pl.BlockSpec((1, bs, c), lambda i, br, bc, ca: (br[i], 0, 0)),
-        pl.BlockSpec((1, bs, 1), lambda i, br, bc, ca: (br[i], 0, 0)),
+        pl.BlockSpec((1, bs, c), by_col),
+        pl.BlockSpec((1, bs, 1), by_col),
+        pl.BlockSpec((1, bs, c), by_row),
+        pl.BlockSpec((1, bs, 1), by_row),
     ]
     out_specs = (
-        pl.BlockSpec((1, bs, c), lambda i, br, bc, ca: (br[i], 0, 0)),
-        pl.BlockSpec((1, 1, 1), lambda i, br, bc, ca: (br[i], 0, 0)),
+        pl.BlockSpec((1, bs, c), by_row),
+        pl.BlockSpec((1, 1, 1), by_row),
     )
+    # a round without visits runs one step whose rows the wrapper drops
+    grid = (jnp.maximum(n_visits, 1),)
     if buffer_depth == 1:
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # block_row, block_col, col_active
-            grid=(n_blocks,),
+            num_scalar_prefetch=3,  # visit_tile, visit_rc, col_active
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bs, bs), lambda i, br, bc, ca: (i, 0, 0)),
+                pl.BlockSpec((1, bs, bs), lambda i, vt, rc, ca: (vt[i], 0, 0)),
                 *fluid_specs,
             ],
             out_specs=out_specs,
         )
-        body = functools.partial(_frontier_kernel, n_blocks=n_blocks)
+        body = _frontier_kernel
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(n_blocks,),
+            grid=grid,
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),  # tile pool stays in HBM
                 *fluid_specs,
@@ -454,7 +481,7 @@ def frontier_round_bsr_pallas(
             ],
         )
         body = functools.partial(
-            _frontier_kernel_dma, n_blocks=n_blocks, depth=buffer_depth
+            _frontier_kernel_dma, n_tiles=n_tiles, depth=buffer_depth
         )
     fn = pl.pallas_call(
         body,
@@ -463,4 +490,4 @@ def frontier_round_bsr_pallas(
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
     )
-    return fn(block_row, block_col, col_active, blocks, f, wt, f, wt)
+    return fn(visit_tile, visit_rc, col_active, blocks, f, wt, f, wt)

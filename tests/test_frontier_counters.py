@@ -2,7 +2,7 @@
 armed-tile tally, the exact §2.3 push count, and the ``repro.*`` host spans.
 
 The armed-tile tally counts, each round, the tiles of the block columns
-that hold a sent node (the columns the fused kernel's predicate arms);
+that hold a sent node (the tiles the fused kernel's grid visits);
 it lives in the driver's loop state, so it banks, checkpoints and rewinds
 with the push count.  Both tallies are two 32-bit words, exact past
 2**31.
@@ -65,6 +65,33 @@ def test_armed_tiles_match_a_numpy_count(problem, interpret,
         assert s.n_armed_tiles == expected
     # some rounds arm some columns, and none arms every tile every round
     assert 0 < expected < 6 * s._driver.m.n_blocks
+
+
+@pytest.mark.parametrize("occupancy_threshold", [0.0, 0.3])
+def test_armed_tiles_count_the_kernels_visits(problem, monkeypatch,
+                                              occupancy_threshold):
+    """Over a grain, the tally equals the sum of the rounds' visit counts,
+    the fused kernel's grid steps: armed share = tally / (rounds * tiles)."""
+    import jax
+
+    from repro.api import session as session_mod
+    from repro.kernels.diffusion import ops
+
+    visits = []
+    real = ops.frontier_round_bsr_pallas
+
+    def counting(blocks, visit_tile, visit_rc, n_visits, *args, **kw):
+        jax.debug.callback(lambda n: visits.append(int(n)), n_visits)
+        return real(blocks, visit_tile, visit_rc, n_visits, *args, **kw)
+
+    monkeypatch.setattr(ops, "frontier_round_bsr_pallas", counting)
+    monkeypatch.setattr(session_mod, "_BSR_ADVANCE", {})
+    s = _session(problem, trace_every=8, interpret=True,
+                 occupancy_threshold=occupancy_threshold)
+    next(s.run())
+    assert len(visits) == s.n_rounds == 8
+    assert s.n_armed_tiles == sum(visits) > 0
+    assert 0 < s.n_armed_tiles < s.n_rounds * s._driver.m.n_blocks
 
 
 def test_armed_tiles_bank_across_warm_start_and_update_graph():

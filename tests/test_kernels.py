@@ -332,6 +332,129 @@ def test_dma_kernels_trace_under_x64_with_f32_data(depth):
         assert np.array_equal(a, b)
 
 
+def _uncompacted_round(m, f, w, t, occupancy_threshold, depth):
+    """The round over the whole tile pool in its row-sorted order: every
+    tile is a visit, and the weights of the unarmed columns are zeroed so
+    that their tiles add exactly nothing and their rows keep their fluid.
+    Returns ``(f_new, sent)`` as ``frontier_round_bsr`` would."""
+    from repro.kernels.diffusion import frontier_round_bsr_pallas
+
+    f2 = f[:, None] if f.ndim == 1 else f
+    bs, c = m.bs, f2.shape[1]
+    wt = (jnp.asarray(w) / jnp.float32(t)).astype(jnp.float32)
+    sel = jnp.abs(jnp.asarray(f2)) * wt[:, None] > 1.0
+    blk = sel.reshape(-1, bs * c)
+    if occupancy_threshold > 0.0:
+        col_active = jnp.mean(blk.astype(jnp.float32), axis=1) > \
+            occupancy_threshold
+    else:
+        col_active = jnp.any(blk, axis=1)
+    sel = sel & col_active.repeat(bs)[:, None]
+    wt = jnp.where(col_active.repeat(bs), wt, 0.0)
+    every = jnp.arange(m.n_blocks, dtype=jnp.int32)
+    out, _ = frontier_round_bsr_pallas(
+        m.blocks, every, m.block_row << 16 | m.block_col,
+        jnp.int32(m.n_blocks), col_active.astype(jnp.int32),
+        jnp.asarray(f2).reshape(-1, bs, c), wt.reshape(-1, bs, 1),
+        m.n_row_blocks, bs=bs, interpret=True, buffer_depth=depth)
+    kept = jnp.where(sel, 0.0, jnp.asarray(f2)).reshape(-1, bs, c)
+    f_new = np.asarray(jnp.where(m.row_occupied[:, None, None], out, kept))
+    sent = np.asarray(jnp.where(sel, jnp.asarray(f2), 0.0))
+    return f_new.reshape(f.shape), sent.reshape(f.shape)
+
+
+def _visit_case(case, c):
+    """A small tile pool, fluid, weights and threshold for one visit-list
+    case (bs = 8, four block rows)."""
+    from repro.kernels.diffusion.ref import dense_to_bsr
+
+    bs, nb = 8, 4
+    rng = np.random.default_rng(17)
+    p = np.zeros((nb * bs, nb * bs), np.float32)
+    band = [(r, q) for r in range(nb) for q in range(nb) if abs(r - q) <= 1]
+    if case == "interleaved_empty_rows":
+        band = [(0, 0), (0, 1), (2, 1), (2, 3)]
+    for r, q in band:
+        p[r * bs:(r + 1) * bs, q * bs:(q + 1) * bs] = \
+            rng.random((bs, bs)) * 0.1
+    if case == "row_unarmed_own_col_armed":
+        # block row 3 owns tiles of columns 0 and 1 only, and only column
+        # 3 is armed: row 3 is not visited though its own column fires
+        p[3 * bs:] = 0.0
+        p[3 * bs:, :2 * bs] = rng.random((bs, 2 * bs)) * 0.1
+    blocks, br, bc = dense_to_bsr(p, bs)
+    m = BsrMatrix(blocks, br, bc, nb, bs)
+    f = (rng.standard_normal((nb * bs, c)) * 0.4).astype(np.float32)
+    w = np.ones(nb * bs, np.float32)
+    t, tau = 1.0, 0.0
+    if case == "none_armed":
+        t = 1e3
+    elif case == "all_armed":
+        f[::bs] = 5.0  # one node above threshold in every column
+    elif case == "row_unarmed_own_col_armed":
+        f[np.abs(f) > t] = 0.0
+        f[3 * bs + 2] = 3.0  # only block column 3 fires
+    elif case == "occupancy_threshold":
+        f[:bs] = 4.0  # column 0 fires densely, the others sparsely
+        tau = 0.25
+    return m, (f[:, 0] if c == 1 else f), w, t, tau
+
+
+@pytest.mark.parametrize("c", [1, 8])
+@pytest.mark.parametrize("depth", [1, 2, 4])
+@pytest.mark.parametrize("case", [
+    "none_armed", "all_armed", "row_unarmed_own_col_armed",
+    "interleaved_empty_rows", "occupancy_threshold"])
+def test_frontier_round_visit_list(case, depth, c):
+    """The kernel's grid runs over the round's visit list, the tiles of the
+    armed columns: ``f_new`` and ``sent`` are bit-identical to the round
+    in the uncompacted tile order, ``res`` agrees to 1e-6 relative, and
+    the block backend and the numpy twin agree within float32."""
+    from repro.kernels.diffusion import compact_visits
+
+    m, f, w, t, tau = _visit_case(case, c)
+    fo, so, ro = frontier_round_bsr(
+        m, jnp.asarray(f), jnp.asarray(w), jnp.float32(t),
+        backend="pallas", interpret=True, buffer_depth=depth,
+        occupancy_threshold=tau)
+    fo, so = np.asarray(fo), np.asarray(so)
+    fu, su = _uncompacted_round(m, f, w, t, tau, depth)
+    assert np.array_equal(fo, fu) and np.array_equal(so, su)
+    assert abs(float(ro) - np.abs(fu.astype(np.float64)).sum()) <= \
+        1e-6 * max(np.abs(fu.astype(np.float64)).sum(), 1e-30)
+    fb, sb, rb = frontier_round_bsr(
+        m, jnp.asarray(f), jnp.asarray(w), jnp.float32(t), backend="block",
+        occupancy_threshold=tau)
+    np.testing.assert_allclose(fo, np.asarray(fb), rtol=2e-5, atol=2e-5)
+    assert np.array_equal(so, np.asarray(sb))
+    if tau == 0.0:
+        fr, sr, rr = frontier_round_ref(
+            np.asarray(m.blocks), np.asarray(m.block_row),
+            np.asarray(m.block_col), f, w, t)
+        np.testing.assert_allclose(fo, fr, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(so, sr, rtol=0, atol=0)
+    # the case is what it says: the visit list it makes
+    sel = np.abs(so.reshape(-1, m.bs * c)) > 0
+    col_active = jnp.asarray(sel.any(axis=1).astype(np.int32))
+    visit_tile, visit_rc, n_visits, row_visited = compact_visits(
+        m, col_active)
+    n = int(n_visits)
+    armed = np.asarray(col_active)[np.asarray(m.block_col)] != 0
+    assert np.array_equal(np.asarray(visit_tile)[:n], np.flatnonzero(armed))
+    packed = np.asarray(m.block_row) << 16 | np.asarray(m.block_col)
+    assert np.array_equal(np.asarray(visit_rc), packed[np.asarray(visit_tile)])
+    assert np.array_equal(np.asarray(row_visited),
+                          np.isin(np.arange(m.n_row_blocks),
+                                  np.asarray(m.block_row)[armed]))
+    expect = {"none_armed": n == 0, "all_armed": n == m.n_blocks,
+              "row_unarmed_own_col_armed":
+                  not row_visited[3] and bool(col_active[3]),
+              "interleaved_empty_rows":
+                  not m.row_occupied[1] and not m.row_occupied[3],
+              "occupancy_threshold": 0 < n < int(armed.size)}
+    assert expect[case], (case, n)
+
+
 def test_frontier_round_rejects_bad_depth():
     m, f, w, t = _frontier_fixture(n=100, c=1)
     with pytest.raises(ValueError):

@@ -69,6 +69,7 @@ def test_frontier_kernel_compiles_for_v5e(one_chip, c, depth):
         _spec((n_blocks, bs, bs), jnp.float32, one_chip),
         _spec((n_blocks,), i32, one_chip),
         _spec((n_blocks,), i32, one_chip),
+        _spec((), i32, one_chip),
         _spec((nrb,), i32, one_chip),
         _spec((nrb, bs, c), jnp.float32, one_chip),
         _spec((nrb, bs, 1), jnp.float32, one_chip),
@@ -126,14 +127,17 @@ def test_frontier_round_at_2_21_fits_one_v5e(one_chip):
         f"{mem.temp_size_in_bytes} bytes exceed one v5e")
 
 
-def test_frontier_grain_at_2_21_fits_one_v5e(one_chip):
-    """The session's whole jitted grain at N = 2^21: the loop of fused
-    rounds with its state, exact counters included.  The kernel keeps the
-    name the benchmark's kernel reader matches, and each stage of a round
-    carries its ``frontier.*`` named scope in the op metadata."""
+@pytest.mark.parametrize("n", [2**21, 2_883_584])
+def test_frontier_grain_at_2_21_fits_one_v5e(one_chip, n):
+    """The session's whole jitted grain at N = 2^21, and at 2,883,584
+    pages, the most that compiles while the kernel's scalar-prefetched
+    lists fit in SMEM: the loop of fused rounds with its state, exact
+    counters included.  The kernel keeps the name the benchmark's kernel
+    reader matches, and each stage of a round carries its ``frontier.*``
+    named scope in the op metadata."""
     from repro.api.session import _bsr_advance
 
-    bs, n = 128, 2**21
+    bs = 128
     nrb = n // bs
     n_blocks = 5 * nrb
     f32, i32, u32 = jnp.float32, jnp.int32, jnp.uint32
@@ -153,8 +157,8 @@ def test_frontier_grain_at_2_21_fits_one_v5e(one_chip):
         occupancy_threshold=0.0).compile()
     text = compiled.as_text()
     assert "%frontier_round_bsr_pallas" in text
-    for stage in ("select", "kernel", "kept", "residual", "history",
-                  "count"):
+    for stage in ("select", "compact", "kernel", "kept", "residual",
+                  "history", "count"):
         assert f"/frontier.{stage}/" in text, stage
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
