@@ -55,8 +55,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 DAMPING = 0.85
 U32 = 2.0 ** -24  # unit roundoff of float32
 REF_TOL = 1e-12
-BUCKET = 128  # engine bucket size = BSR block size
-HEADROOM = 2  # inert bucket rows per device for dynamic moves
+BUCKET = 128  # engine bucket size = BSR block size (the default rule's)
 COMPILE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -233,11 +232,10 @@ def phase_solve(n: int = 2**21) -> None:
 def _engine_session(problem, k: int, **kw):
     import repro
 
+    knobs = _kernel_knobs()
     return repro.SolverSession(
-        problem, method="engine:bsr", k=k,
-        buckets_per_dev=problem.n // (k * BUCKET) + HEADROOM,
-        headroom=HEADROOM, buffer_depth=_kernel_knobs()["buffer_depth"],
-        **kw)
+        problem, method="engine:bsr", k=k, bs=knobs["bs"],
+        buffer_depth=knobs["buffer_depth"], **kw)
 
 
 def phase_engine(n: int = 2**20) -> None:

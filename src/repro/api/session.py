@@ -35,7 +35,7 @@ from typing import Iterator, List, Optional, Tuple
 import numpy as np
 
 from repro.kernels.tune import resolved_config
-from repro.obs import span
+from repro.obs import count_add, count_value, span
 
 from .options import SolverOptions
 from .problem import Problem
@@ -345,22 +345,6 @@ class _SegmentSumDriver:
                 np.asarray(ops)[:c], int(rounds), res_cols, stats)
 
 
-def _count_add(count, d):
-    """Add ``d`` (an int, 0 <= d < 2**32) to the exact tally ``count``:
-    ``[high, low]`` uint32 words of ``high * 2**32 + low``.  An int32
-    counter would wrap within one long solve (about 2**31 edge pushes)."""
-    import jax.numpy as jnp
-
-    low = count[1] + d.astype(jnp.uint32)
-    return jnp.stack([count[0] + (low < count[1]).astype(jnp.uint32), low])
-
-
-def _count_value(count) -> int:
-    """The Python int an exact tally (:func:`_count_add`) holds."""
-    high, low = (int(v) for v in np.asarray(count))
-    return high << 32 | low
-
-
 _BSR_ADVANCE: dict = {}
 
 
@@ -372,7 +356,7 @@ def _bsr_advance():
     one compiled program instead of tracing and compiling a new loop.
     The loop state is ``(f, |f|_1, h, t, ops, rounds, armed)``: ``ops``
     counts the §2.3 edge pushes and ``armed`` the tiles the fused kernel
-    visits (its grid steps), both exact tallies (:func:`_count_add`).
+    visits (its grid steps), both exact tallies (:func:`count_add`).
     The stages of a round carry ``frontier.*`` named scopes, so a device
     trace names the stage each operation belongs to.
     """
@@ -416,8 +400,8 @@ def _bsr_advance():
                                     axis=1)
                 darmed = jnp.sum(jnp.where(col_armed, col_tiles, 0))
                 t_new = jnp.where(jnp.any(sel), t, t / gamma)
-            return (f_new, res, h, t_new, _count_add(ops, dops),
-                    rounds + 1, _count_add(armed, darmed))
+            return (f_new, res, h, t_new, count_add(ops, dops),
+                    rounds + 1, count_add(armed, darmed))
 
         return jax.lax.while_loop(cond, body, state)
 
@@ -526,7 +510,7 @@ class _BsrFrontierDriver:
 
     def ops(self) -> int:
         with span("readback.ops"):
-            return _count_value(self._state[4])
+            return count_value(self._state[4])
 
     def rounds(self) -> int:
         with span("readback.rounds"):
@@ -536,7 +520,7 @@ class _BsrFrontierDriver:
         """Tiles the fused kernel visited, summed over the rounds of the
         current phase."""
         with span("readback.armed_tiles"):
-            return _count_value(self._state[6])
+            return count_value(self._state[6])
 
     def exhausted(self) -> bool:
         return False
@@ -568,14 +552,17 @@ class _BsrFrontierDriver:
 # engine driver (shard_map production solver, chunk-granular)
 # --------------------------------------------------------------------------- #
 def _bsr_buckets_per_dev(n: int, k: int, options: SolverOptions) -> int:
-    """BSR tiles are dense [S, S] blocks: cap the bucket size (≤ 512)
-    so the tile pool stays MXU-shaped instead of ballooning to
-    [R, T, N/K, N/K] on big problems.  Auto-sizing only ever *raises*
-    the bucket count the caller configured.  One rule shared by driver
-    construction and mid-solve rescale, so a rescaled engine's layout
-    always matches what a cold start at the same k would build."""
-    max_s = 512
-    real_needed = -(-n // (k * max_s))  # ceil
+    """Bucket rows per device for ``engine:bsr``: enough real buckets that
+    a bucket holds at most the resolved BSR block ``bs`` (explicit option >
+    tuned record > 128), plus ``headroom``.  A bucket is then one [bs, bs]
+    tile row, as on the frontier path: on a host-ordered crawl it is one
+    host, and its tiles reach only the neighbouring hosts, in any order of
+    the hosts.  Auto-sizing only ever *raises* the bucket count the caller
+    configured.  One rule shared by driver construction and mid-solve
+    rescale, so a rescaled engine's layout always matches what a cold
+    start at the same k would build."""
+    bs = resolved_config("bsr_gather_spmm", bs=options.bs)[0]
+    real_needed = -(-n // (k * bs))  # ceil
     return max(options.buckets_per_dev, real_needed + options.headroom)
 
 
@@ -742,7 +729,7 @@ class _EngineDriver:
             f=put_row(f_cur.reshape(st.f.shape).astype(cfg.dtype)),
             outbox=put_row(jnp.zeros_like(st.outbox)),
             t=put_row(t0.astype(cfg.dtype)),
-            ops=put_row(jnp.zeros(cfg.k, dtype=jnp.int32)),
+            ops=put_row(jnp.zeros((cfg.k, 2), dtype=jnp.uint32)),
             rounds=jax.device_put(jnp.zeros((), dtype=jnp.int32),
                                   eng.rep_sharding),
         )
@@ -758,15 +745,20 @@ class _EngineDriver:
     def advance(self, tol: float, round_limit: int) -> None:
         """One jitted chunk + one control-plane pass (engine grain)."""
         eng, ex = self.engine, self.ex
-        ex.state, stats = eng._chunk(ex.state, *ex.chunk_operands())
-        r = np.asarray(stats["r"])
-        s_ = np.asarray(stats["s"])
-        self._resid = float(np.asarray(stats["residual"])) + float(s_.sum())
+        with span("dispatch"):
+            ex.state, stats = eng._chunk(ex.state, *ex.chunk_operands())
+        # the first read waits for the chunk, the others for their transfer
+        with span("readback.stats"):
+            r = np.asarray(stats["r"])
+            s_ = np.asarray(stats["s"])
+            self._resid = (float(np.asarray(stats["residual"]))
+                           + float(s_.sum()))
         self._chunks += 1
         if self._resid <= tol:
             return
-        self._prev_ops = eng.apply_control_plane(
-            ex, r, s_, self._chunks, self._prev_ops, self._moves)
+        with span("control"):
+            self._prev_ops = eng.apply_control_plane(
+                ex, r, s_, self._chunks, self._prev_ops, self._moves)
 
     def x(self) -> np.ndarray:
         return self.engine.extract_solution(self.ex.state,
@@ -776,8 +768,9 @@ class _EngineDriver:
         return self._resid
 
     def ops(self) -> int:
-        return self._ops_offset + int(
-            np.asarray(self.ex.state.ops).astype(np.int64).sum())
+        from repro.core.distributed import device_ops
+
+        return self._ops_offset + int(device_ops(self.ex.state.ops).sum())
 
     def rounds(self) -> int:
         return self._rounds_offset + int(np.asarray(self.ex.state.rounds))
@@ -790,10 +783,14 @@ class _EngineDriver:
 
     # ---- checkpointable state (node space) --------------------------------
     def fluid(self) -> Tuple[np.ndarray, np.ndarray]:
-        return (self.engine.gather_nodes(self.ex.state.f,
-                                         self.ex.row_of_bucket),
-                self.engine.gather_nodes(self.ex.state.h,
-                                         self.ex.row_of_bucket))
+        """``(F, H)`` in node space; F holds the fluid still in the
+        devices' outboxes too (none between chunks: every exchange cycle
+        ends by delivering it)."""
+        st, rob = self.ex.state, self.ex.row_of_bucket
+        f = np.asarray(st.f, dtype=np.float64).reshape(-1)
+        f = f + np.asarray(st.outbox, dtype=np.float64).sum(axis=0)
+        return (self.engine.gather_nodes(f, rob),
+                self.engine.gather_nodes(st.h, rob))
 
     def threshold(self) -> np.ndarray:
         return np.asarray(self.ex.state.t, dtype=np.float64)

@@ -35,6 +35,7 @@ production meshes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -46,6 +47,7 @@ from repro.balance.executors import BucketMoveExecutor
 from repro.balance.plan import MovePlan
 from repro.balance.policies import Rebalancer, make_rebalancer
 from repro.balance.signals import LoadSignal
+from repro.obs import count_add
 from repro.parallel.compat import shard_map
 
 from .graph import CSRGraph  # noqa: F401  (public signature type)
@@ -280,8 +282,10 @@ class EngineState:
     """Sharded solver state.
 
     ``f``/``h`` are [R, S] sharded on rows; ``outbox`` is [K, R*S] sharded on
-    its first axis (each device holds ITS full-length outbox); ``t``/``ops``
-    are [K] sharded one-per-device; ``pos_of_bucket`` is replicated.
+    its first axis (each device holds ITS full-length outbox); ``t`` is [K]
+    and ``ops`` [K, 2] (each device's §2.3 push count as the two uint32
+    words of :func:`repro.obs.count_add`, exact past 2**31), both sharded
+    one-per-device; ``pos_of_bucket`` is replicated.
     """
 
     f: jax.Array
@@ -308,6 +312,13 @@ class EngineState:
 # ---------------------------------------------------------------------- #
 _MESH_CACHE: dict = {}
 _CHUNK_CACHE: dict = {}
+
+
+def device_ops(ops) -> np.ndarray:
+    """Each device's push count, as int64, from the [K, 2] word pairs of
+    ``EngineState.ops``."""
+    words = np.asarray(ops).astype(np.int64)
+    return words[:, 0] << 32 | words[:, 1]
 
 
 def _shared_mesh(devs, axis: str) -> Mesh:
@@ -442,7 +453,7 @@ class DistributedEngine:
             ),
             t=put_row(t0),
             pos_of_bucket=put_rep(a.pos_of_bucket.astype(np.int32)),
-            ops=put_row(np.zeros(cfg.k, dtype=np.int32)),
+            ops=put_row(np.zeros((cfg.k, 2), dtype=np.uint32)),
             rounds=put_rep(np.zeros((), dtype=np.int32)),
         )
 
@@ -498,50 +509,51 @@ class DistributedEngine:
             selected dangling node).
             """
             w, src_slot, dst_bucket, dst_slot, wgt = operands[:5]
-            fw = jnp.abs(f) * w
-            sel = fw > t_d  # [B_loc, S]
-            any_sel = jnp.any(sel)
-            sent = jnp.where(sel, f, jnp.zeros_like(f))
-            h = h + sent
-            f = f - sent
-            if use_bsr:
-                tiles, tile_dst = operands[5], operands[6]
-                contrib = tile_push(tiles, tile_dst, sent, pos, visits)
-            else:
-                row_idx = jnp.arange(f.shape[0])[:, None]
-                msg = sent[row_idx, src_slot] * wgt  # [B_loc, E]
-                flat_dst = pos[dst_bucket] * s + dst_slot  # [B_loc, E]
-                contrib = jax.ops.segment_sum(
-                    msg.reshape(-1), flat_dst.reshape(-1),
-                    num_segments=r_total * s,
+            with jax.named_scope("engine.select"):
+                fw = jnp.abs(f) * w
+                sel = fw > t_d  # [B_loc, S]
+                any_sel = jnp.any(sel)
+                sent = jnp.where(sel, f, jnp.zeros_like(f))
+                h = h + sent
+                f = f - sent
+                t_d = jnp.where(any_sel, t_d, t_d / cfg.gamma)
+                if use_bsr:
+                    # same §2.3 count without the per-edge gather: every
+                    # slot's real edges all fire when the slot is selected
+                    dops = jnp.sum(jnp.where(sel, operands[7], 0))
+                else:
+                    row_idx = jnp.arange(f.shape[0])[:, None]
+                    active_edges = sel[row_idx, src_slot] & (wgt != 0)
+                    dops = jnp.sum(active_edges.astype(jnp.int32))
+                dops = dops + jnp.sum((sel & dang).astype(jnp.int32))
+                ops_d = count_add(ops_d, dops)
+            with jax.named_scope("engine.push"):
+                if use_bsr:
+                    tiles, tile_dst = operands[5], operands[6]
+                    contrib = tile_push(tiles, tile_dst, sent, pos, visits)
+                else:
+                    row_idx = jnp.arange(f.shape[0])[:, None]
+                    msg = sent[row_idx, src_slot] * wgt  # [B_loc, E]
+                    flat_dst = pos[dst_bucket] * s + dst_slot  # [B_loc, E]
+                    contrib = jax.ops.segment_sum(
+                        msg.reshape(-1), flat_dst.reshape(-1),
+                        num_segments=r_total * s,
+                    )
+                mine = jax.lax.dynamic_slice(
+                    contrib, (my_start,), (b_loc * s,)
+                ).reshape(f.shape)
+                f = f + mine
+                contrib = jax.lax.dynamic_update_slice(
+                    contrib, jnp.zeros(b_loc * s, contrib.dtype), (my_start,)
                 )
-            mine = jax.lax.dynamic_slice(
-                contrib, (my_start,), (b_loc * s,)
-            ).reshape(f.shape)
-            f = f + mine
-            contrib = jax.lax.dynamic_update_slice(
-                contrib, jnp.zeros(b_loc * s, contrib.dtype), (my_start,)
-            )
-            obox = obox + contrib
-            t_d = jnp.where(any_sel, t_d, t_d / cfg.gamma)
-            if use_bsr:
-                # same §2.3 count without the per-edge gather: every slot's
-                # real edges all fire when the slot is selected
-                slot_deg = operands[7]
-                ops_d = ops_d + jnp.sum(
-                    jnp.where(sel, slot_deg, 0)).astype(jnp.int32)
-            else:
-                row_idx = jnp.arange(f.shape[0])[:, None]
-                active_edges = sel[row_idx, src_slot] & (wgt != 0)
-                ops_d = ops_d + jnp.sum(active_edges).astype(jnp.int32)
-            ops_d = ops_d + jnp.sum(sel & dang).astype(jnp.int32)
+                obox = obox + contrib
             return f, h, obox, t_d, ops_d
 
         def chunk(f, h, outbox, t, pos, ops, rounds, *operands):
             """shard_map body.  Per-device shards:
 
             f, h, w, src_slot, ...: [B_loc, S] / [B_loc, E]
-            outbox: [1, R*S]   t, ops: [1]   pos: [R] replicated
+            outbox: [1, R*S]   t: [1]   ops: [1, 2]   pos: [R] replicated
             operands: w, src_slot, dst_bucket, dst_slot, wgt
             [, tiles [B_loc, T, S, S], tile_dst [B_loc, T],
              slot_out_deg [B_loc, S] when bsr]
@@ -554,8 +566,9 @@ class DistributedEngine:
             # visit tables depend only on tile_dst: compute once per chunk,
             # not once per round (argsort inside the while_loop body would
             # not be hoisted by XLA)
-            visits = (_tile_visit_order(operands[6], r_total)
-                      if use_bsr and pallas_path else None)
+            with jax.named_scope("engine.push"):
+                visits = (_tile_visit_order(operands[6], r_total)
+                          if use_bsr and pallas_path else None)
             # dangling-slot mask: real node (w != 0) with zero real edges.
             # Loop-invariant given the operands; the bsr path reads it off
             # the prebuilt per-slot degrees, the per-edge path rebuilds
@@ -576,10 +589,11 @@ class DistributedEngine:
                 f, h, obox, t_d, ops_d = local_round(
                     f, h, obox, t_d, ops_d, pos, operands, my_start,
                     visits, dang)
-                r_k = jnp.sum(jnp.abs(f))
-                s_k = jnp.sum(jnp.abs(obox))
-                fire_local = (s_k > r_k / 2.0).astype(jnp.int32)
-                fire = jax.lax.pmax(fire_local, axis)
+                with jax.named_scope("engine.residual"):
+                    r_k = jnp.sum(jnp.abs(f))
+                    s_k = jnp.sum(jnp.abs(obox))
+                    fire_local = (s_k > r_k / 2.0).astype(jnp.int32)
+                    fire = jax.lax.pmax(fire_local, axis)
                 return f, h, obox, t_d, ops_d, i + 1, fire
 
             def cond(carry):
@@ -592,26 +606,27 @@ class DistributedEngine:
                  jnp.zeros((), jnp.int32)),
             )
             # ---- fluid exchange: reduce-scatter outbox over devices ----
-            r_before = jnp.sum(jnp.abs(f))
-            delta = jax.lax.psum_scatter(
-                obox.reshape(k, b_loc * s), axis, scatter_dimension=0,
-                tiled=False,
-            ).reshape(f.shape)
-            f = f + delta
-            received = jnp.sum(jnp.abs(delta))
-            t_new = jnp.where(
-                received > 0,
-                jnp.minimum(
-                    jnp.where(
-                        r_before > 0,
-                        t_d * (r_before + received) / r_before,
+            with jax.named_scope("engine.exchange"):
+                r_before = jnp.sum(jnp.abs(f))
+                delta = jax.lax.psum_scatter(
+                    obox.reshape(k, b_loc * s), axis, scatter_dimension=0,
+                    tiled=False,
+                ).reshape(f.shape)
+                f = f + delta
+                received = jnp.sum(jnp.abs(delta))
+                t_new = jnp.where(
+                    received > 0,
+                    jnp.minimum(
+                        jnp.where(
+                            r_before > 0,
+                            t_d * (r_before + received) / r_before,
+                            received,
+                        ),
                         received,
                     ),
-                    received,
-                ),
-                t_d,
-            )
-            obox = jnp.zeros_like(obox)
+                    t_d,
+                )
+                obox = jnp.zeros_like(obox)
             return (f, h, obox[None], t_new[None], pos, ops_d[None],
                     rounds + i)
 
@@ -625,7 +640,17 @@ class DistributedEngine:
             check_vma=False,
         )
 
-        @jax.jit
+        # the outputs keep the shardings ``init_state`` gives the inputs:
+        # left to the compiler, a one-device axis hands ``t`` and ``ops``
+        # back replicated, and the next call would trace again
+        row = NamedSharding(self.mesh, pr)
+        rep = NamedSharding(self.mesh, pp)
+        out_shardings = (
+            EngineState(f=row, h=row, outbox=row, t=row, pos_of_bucket=rep,
+                        ops=row, rounds=rep),
+            {"r": rep, "s": rep, "residual": rep})
+
+        @functools.partial(jax.jit, out_shardings=out_shardings)
         def run_chunk(state: EngineState, *operands):
             f, h, outbox, t, pos, ops, rounds = (
                 state.f, state.h, state.outbox, state.t,
@@ -635,11 +660,12 @@ class DistributedEngine:
                     f, h, outbox, t, pos, ops, rounds, *operands)
             new = EngineState(f=f, h=h, outbox=outbox, t=t,
                               pos_of_bucket=pos, ops=ops, rounds=rounds)
-            stats = {
-                "r": jnp.sum(jnp.abs(f.reshape(cfg.k, -1)), axis=1),
-                "s": jnp.sum(jnp.abs(outbox), axis=1),
-                "residual": jnp.sum(jnp.abs(f)),
-            }
+            with jax.named_scope("engine.residual"):
+                stats = {
+                    "r": jnp.sum(jnp.abs(f.reshape(cfg.k, -1)), axis=1),
+                    "s": jnp.sum(jnp.abs(outbox), axis=1),
+                    "residual": jnp.sum(jnp.abs(f)),
+                }
             return new, stats
 
         return run_chunk
@@ -656,14 +682,15 @@ class DistributedEngine:
             arrs = tuple(
                 jax.device_put(x, self.row_sharding) for x in arrs
             )
+            # and every field keeps init_state's sharding, so the next
+            # chunk call finds its compiled program
+            row = lambda x: jax.device_put(x, self.row_sharding)
+            rep = lambda x: jax.device_put(x, self.rep_sharding)
             new_state = EngineState(
-                f=jax.device_put(new_state.f, self.row_sharding),
-                h=jax.device_put(new_state.h, self.row_sharding),
-                outbox=new_state.outbox,
-                t=new_state.t,
-                pos_of_bucket=new_state.pos_of_bucket,
-                ops=new_state.ops,
-                rounds=new_state.rounds,
+                f=row(new_state.f), h=row(new_state.h),
+                outbox=row(new_state.outbox), t=row(new_state.t),
+                pos_of_bucket=rep(new_state.pos_of_bucket),
+                ops=row(new_state.ops), rounds=rep(new_state.rounds),
             )
             return new_state, arrs
 
@@ -699,7 +726,7 @@ class DistributedEngine:
                 ex, r, s_, chunk_i, prev_ops, move_log)
         n_moves = len(move_log)
         x = self.extract_solution(ex.state, ex.row_of_bucket)
-        ops = np.asarray(ex.state.ops).copy()
+        ops = device_ops(ex.state.ops)
         return x, {
             "residual": resid,
             "chunks": chunk_i + 1,
@@ -709,7 +736,7 @@ class DistributedEngine:
             "history": history,
             "converged": resid <= tol,
             "ops": ops,
-            "n_edge_ops": int(ops.astype(np.int64).sum()),
+            "n_edge_ops": int(ops.sum()),
         }
 
     def apply_control_plane(self, ex, r: np.ndarray, s_: np.ndarray,
@@ -727,11 +754,9 @@ class DistributedEngine:
         scale = (self.load_scale if self.load_scale is not None
                  else np.ones(self.cfg.k))
         if self.cfg.signal == "edge-ops":
-            ops = np.asarray(ex.state.ops).astype(np.int64)
-            # the on-device counter is int32 and cumulative over the
-            # whole solve; recover the true per-chunk delta through
-            # wraparound (valid while one chunk stays under 2^32 ops)
-            delta = (ops - prev_ops) & 0xFFFFFFFF
+            # the on-device counter is cumulative over the whole solve
+            ops = device_ops(ex.state.ops)
+            delta = ops - prev_ops
             sig = LoadSignal.from_edge_ops(delta * scale, sizes, step=step)
             prev_ops = ops
         else:

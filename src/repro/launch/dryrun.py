@@ -422,7 +422,7 @@ def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
         outbox=row(k, r * bucket_size),
         t=row(k),
         pos_of_bucket=rowi(r),
-        ops=sds((k,), jnp.int32),
+        ops=sds((k, 2), jnp.uint32),
         rounds=sds((), jnp.int32),
     )
     sh = lambda spec: NamedSharding(flat_mesh, spec)

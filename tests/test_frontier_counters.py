@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 import repro
-from repro.api.session import _count_add, _count_value
+from repro.obs import count_add, count_value
 from repro.core.graph import host_block_graph
 from repro.graph import rotation_churn
 
@@ -138,8 +138,8 @@ def test_exact_tally_adds_past_32_bits(start, add):
     import jax.numpy as jnp
 
     count = jnp.asarray([start >> 32, start & 0xFFFFFFFF], jnp.uint32)
-    got = _count_add(count, jnp.asarray(add, jnp.uint32))
-    assert _count_value(got) == start + add
+    got = count_add(count, jnp.asarray(add, jnp.uint32))
+    assert count_value(got) == start + add
 
 
 def test_push_count_is_exact_past_2_31(problem):
