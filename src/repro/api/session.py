@@ -649,6 +649,7 @@ class _EngineDriver:
         # the new width, so phase totals accumulate into host offsets
         self._ops_offset = 0
         self._rounds_offset = 0
+        self._armed_offset = 0
         self._warm_maps = None  # (arrays-identity, layout-bytes, maps)
         self._seeded = True
 
@@ -732,6 +733,7 @@ class _EngineDriver:
             ops=put_row(jnp.zeros((cfg.k, 2), dtype=jnp.uint32)),
             rounds=jax.device_put(jnp.zeros((), dtype=jnp.int32),
                                   eng.rep_sharding),
+            armed=put_row(jnp.zeros((cfg.k, 2), dtype=jnp.uint32)),
         )
         self._fresh_rebalancer()
         self._resid = float(jnp.abs(f_node).sum())
@@ -740,6 +742,7 @@ class _EngineDriver:
         self._prev_ops = np.zeros(cfg.k, dtype=np.int64)
         self._ops_offset = 0
         self._rounds_offset = 0
+        self._armed_offset = 0
         return self._resid
 
     def advance(self, tol: float, round_limit: int) -> None:
@@ -774,6 +777,18 @@ class _EngineDriver:
 
     def rounds(self) -> int:
         return self._rounds_offset + int(np.asarray(self.ex.state.rounds))
+
+    def armed_tiles(self) -> Optional[int]:
+        """Tiles of the rows that sent fluid, summed over the local rounds
+        of the current phase: the gather kernel's grid steps.  None on the
+        per-edge path, which has no tile pool."""
+        from repro.core.distributed import device_ops
+
+        if self.cfg.diffusion_backend != "bsr":
+            return None
+        with span("readback.armed_tiles"):
+            return self._armed_offset + int(
+                device_ops(self.ex.state.armed).sum())
 
     def exhausted(self) -> bool:
         return self._chunks >= self.cfg.max_chunks
@@ -829,6 +844,7 @@ class _EngineDriver:
         if k_new == self.cfg.k:
             return []
         prev_ops, prev_rounds = self.ops(), self.rounds()
+        prev_armed = self.armed_tiles()
         bpd = (_bsr_buckets_per_dev(self.problem.n, k_new, self.options)
                if self.cfg.diffusion_backend == "bsr"
                else self.options.buckets_per_dev)
@@ -850,6 +866,7 @@ class _EngineDriver:
         self._prev_ops = np.zeros(k_new, dtype=np.int64)
         self._ops_offset = prev_ops
         self._rounds_offset = prev_rounds
+        self._armed_offset = prev_armed or 0
         return drains
 
     def note_graph_churn(self, churn_per_node: np.ndarray) -> None:
@@ -984,10 +1001,12 @@ class SolverSession:
 
     @property
     def n_armed_tiles(self) -> Optional[int]:
-        """Tiles the fused frontier kernel visited in the current solve
-        phase, summed over its rounds: its grid steps, the tiles of the
-        block columns holding a sent node.  None for a backend without a
-        tile pool.  One device read, made only when asked."""
+        """Tiles the diffusion kernel visited in the current solve phase,
+        summed over its rounds: its grid steps.  On ``frontier:pallas``
+        the tiles of the block columns holding a sent node, on
+        ``engine:bsr`` the tiles of the bucket rows holding one.  None for
+        a backend without a tile pool.  One device read, made only when
+        asked."""
         armed = getattr(self._driver, "armed_tiles", None)
         return None if armed is None else armed()
 

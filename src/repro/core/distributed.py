@@ -191,51 +191,81 @@ def _tile_push_stable(
     use_pallas: bool,
     interpret: bool = False,
     buffer_depth: int = 1,
-    visits: Optional[tuple] = None,
+    row_armed: Optional[jax.Array] = None,  # [B_loc] bool
 ) -> jax.Array:
     """delta[bid] = sum of tile @ sent over tiles targeting stable bucket bid.
 
     Two implementations of the same contraction:
 
     * Pallas (TPU / forced-interpret): the tiles stay in their row-owned
-      pool; an in-graph ``argsort`` of the destination ids builds the
-      dst-sorted visit order that :func:`bsr_gather_spmm_pallas` consumes via
-      scalar prefetch, and the visit-derived occupancy map masks buckets no
-      tile targets (their output blocks are uninitialised by design).
+      pool, and :func:`bsr_gather_spmm_pallas` walks the round's visit list
+      (:func:`_armed_visits`): the tiles of the rows that send fluid
+      (``row_armed``), in destination order.  A row with nothing selected
+      sends an all-zero ``sent`` row, so its tiles would add exact zeros:
+      they cost no grid step.  The buckets no visit reaches are masked to
+      zero (the kernel leaves their output blocks uninitialised).  With
+      ``row_armed`` None every tile is a visit, the grid spans the whole
+      pool, and the result is the same up to the sign of a zero.
     * einsum + segment-sum: XLA batched-matmul path, the CPU default.
 
     Padding tiles are all-zero and point at bucket 0 — they contribute
     nothing either way.
     """
     b_loc, t_cap, s, _ = tiles.shape
-    dst_flat = tile_dst.reshape(-1)
     if use_pallas:
         from repro.kernels.diffusion import bsr_gather_spmm_pallas
 
-        order, visit_dst, visit_col, occ = (
-            visits if visits is not None
-            else _tile_visit_order(tile_dst, r_total))
+        every_tile = row_armed is None
+        if every_tile:
+            row_armed = jnp.ones(b_loc, bool)
+        visit_block, visit_row, visit_col, n_visits, reached = _armed_visits(
+            tile_dst, row_armed, r_total)
         out = bsr_gather_spmm_pallas(
-            tiles.reshape(-1, s, s), order, visit_dst, visit_col,
+            tiles.reshape(-1, s, s), visit_block, visit_row, visit_col,
             sent[:, :, None], r_total, bs=s, interpret=interpret,
             buffer_depth=buffer_depth,
+            n_visits=None if every_tile else n_visits,
         )
-        return jnp.where(occ[:, None], out[..., 0], jnp.zeros_like(out[..., 0]))
+        return jnp.where(reached[:, None], out[..., 0],
+                         jnp.zeros_like(out[..., 0]))
     partial = jnp.einsum("btij,bj->bti", tiles, sent)
     return jax.ops.segment_sum(
-        partial.reshape(-1, s), dst_flat, num_segments=r_total
+        partial.reshape(-1, s), tile_dst.reshape(-1), num_segments=r_total
     )
 
 
-def _tile_visit_order(tile_dst: jax.Array, r_total: int):
-    """dst-sorted visit tables for the gather kernel + the row-occupancy
-    mask.  Loop-invariant given ``tile_dst`` — hoist out of the round loop
-    (an ``argsort`` per round would partially undo the kernel's win)."""
+def _armed_visits(tile_dst: jax.Array, row_armed: jax.Array, r_total: int):
+    """The round's visit list for the gather kernel.
+
+    Returns ``(visit_block, visit_row, visit_col, n_visits, reached)``: the
+    first ``n_visits`` entries are the pool slots of the armed rows, sorted
+    by destination bucket, ties in pool order (the order an all-slot push
+    accumulates them in); the other slots follow, sorted the same way.
+    ``visit_row`` is each slot's destination, ``visit_col`` its source row,
+    and ``reached`` marks the buckets that at least one visit targets.
+
+    One stable sort on "not armed, then destination" over the pool builds
+    the list, and the reached buckets are a one-hot product on the MXU: a
+    TPU gathers and scatters scalars one at a time.
+    """
+    from repro.kernels.diffusion.ops import _lane_split_one_hot
+
     t_cap = tile_dst.shape[1]
-    dst_flat = tile_dst.reshape(-1)
-    order = jnp.argsort(dst_flat).astype(jnp.int32)
-    occ = jnp.zeros(r_total, bool).at[dst_flat].set(True)
-    return (order, dst_flat[order], (order // t_cap).astype(jnp.int32), occ)
+    dst = tile_dst.reshape(-1)
+    armed = jnp.broadcast_to(row_armed[:, None], tile_dst.shape).reshape(-1)
+    key = dst + jnp.where(armed, 0, r_total).astype(dst.dtype)
+    key, visit_block = jax.lax.sort(
+        (key, jnp.arange(dst.shape[0], dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    visit_row = jnp.where(key < r_total, key, key - r_total)
+    n_visits = t_cap * jnp.sum(row_armed, dtype=jnp.int32)
+    hi, lo = _lane_split_one_hot(dst, r_total)
+    visits_in_row = jax.lax.dot_general(
+        hi, lo * armed[:, None].astype(jnp.bfloat16),
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    reached = visits_in_row.reshape(-1)[:r_total] > 0
+    return (visit_block, visit_row, visit_block // t_cap, n_visits,
+            reached)
 
 
 def _tile_engine_edges(
@@ -285,7 +315,10 @@ class EngineState:
     its first axis (each device holds ITS full-length outbox); ``t`` is [K]
     and ``ops`` [K, 2] (each device's §2.3 push count as the two uint32
     words of :func:`repro.obs.count_add`, exact past 2**31), both sharded
-    one-per-device; ``pos_of_bucket`` is replicated.
+    one-per-device; ``pos_of_bucket`` is replicated.  ``armed`` [K, 2]
+    counts each device's armed tiles the same way: the tiles of the rows
+    that sent fluid, summed over local rounds — the gather kernel's grid
+    steps on the bsr path, zero on the per-edge path.
     """
 
     f: jax.Array
@@ -295,6 +328,7 @@ class EngineState:
     pos_of_bucket: jax.Array
     ops: jax.Array
     rounds: jax.Array
+    armed: jax.Array
 
 
 # ---------------------------------------------------------------------- #
@@ -315,8 +349,8 @@ _CHUNK_CACHE: dict = {}
 
 
 def device_ops(ops) -> np.ndarray:
-    """Each device's push count, as int64, from the [K, 2] word pairs of
-    ``EngineState.ops``."""
+    """Each device's count, as int64, from the [K, 2] word pairs of
+    ``EngineState.ops`` (or ``.armed``)."""
     words = np.asarray(ops).astype(np.int64)
     return words[:, 0] << 32 | words[:, 1]
 
@@ -336,7 +370,7 @@ def _repart(state: EngineState, row_perm, new_pos, operands):
     new_state = EngineState(
         f=take(state.f), h=take(state.h), outbox=state.outbox,
         t=state.t, pos_of_bucket=new_pos, ops=state.ops,
-        rounds=state.rounds)
+        rounds=state.rounds, armed=state.armed)
     return new_state, tuple(take(x) for x in operands)
 
 
@@ -455,6 +489,7 @@ class DistributedEngine:
             pos_of_bucket=put_rep(a.pos_of_bucket.astype(np.int32)),
             ops=put_row(np.zeros((cfg.k, 2), dtype=np.uint32)),
             rounds=put_rep(np.zeros((), dtype=np.int32)),
+            armed=put_row(np.zeros((cfg.k, 2), dtype=np.uint32)),
         )
 
     def _to_slots(self, v_nodes: np.ndarray) -> np.ndarray:
@@ -480,17 +515,17 @@ class DistributedEngine:
         pallas_path = (jax.default_backend() == "tpu"
                        or cfg.pallas_interpret)
 
-        def tile_push(tiles, tile_dst, sent, pos, visits):
+        def tile_push(tiles, tile_dst, sent, pos, row_armed):
             """BSR push: dense [S, S] tile matmuls instead of the per-edge
             scatter.  Returns the full-length [R*S] contribution in *row*
-            space (current bucket positions).  ``visits`` is the chunk-level
-            precomputed dst-sorted visit table (pallas path only)."""
+            space (current bucket positions).  ``row_armed`` marks the
+            rows that send fluid: only their tiles are visited."""
             contrib_stable = _tile_push_stable(
                 tiles, tile_dst, sent, r_total,
                 use_pallas=pallas_path,
                 interpret=cfg.pallas_interpret,
                 buffer_depth=cfg.pallas_buffer_depth,
-                visits=visits,
+                row_armed=row_armed,
             )  # [R, S] indexed by stable bucket id
             # stable bucket space -> current row space via the position map
             inv = jnp.zeros(r_total, jnp.int32).at[pos].set(
@@ -498,15 +533,16 @@ class DistributedEngine:
             )
             return contrib_stable[inv].reshape(-1)
 
-        def local_round(f, h, obox, t_d, ops_d, pos, operands, my_start,
-                        visits, dang):
+        def local_round(f, h, obox, t_d, ops_d, armed_d, pos, operands,
+                        my_start, dang):
             """One frontier round on this device's [B_loc, S] rows.
 
             ``obox`` is the device's full-length [R*S] outbox; ``dang``
             is the [B_loc, S] dangling-slot mask (real node, zero real
             edges) charged one op per selected round — the §2.3
             accounting every other tier uses (edge pushes plus one per
-            selected dangling node).
+            selected dangling node).  ``armed_d`` tallies the tiles of
+            the rows with a selected node (bsr only).
             """
             w, src_slot, dst_bucket, dst_slot, wgt = operands[:5]
             with jax.named_scope("engine.select"):
@@ -521,6 +557,10 @@ class DistributedEngine:
                     # same §2.3 count without the per-edge gather: every
                     # slot's real edges all fire when the slot is selected
                     dops = jnp.sum(jnp.where(sel, operands[7], 0))
+                    row_armed = jnp.any(sel, axis=1)
+                    armed_d = count_add(armed_d, operands[6].shape[1]
+                                        * jnp.sum(row_armed,
+                                                  dtype=jnp.int32))
                 else:
                     row_idx = jnp.arange(f.shape[0])[:, None]
                     active_edges = sel[row_idx, src_slot] & (wgt != 0)
@@ -530,7 +570,8 @@ class DistributedEngine:
             with jax.named_scope("engine.push"):
                 if use_bsr:
                     tiles, tile_dst = operands[5], operands[6]
-                    contrib = tile_push(tiles, tile_dst, sent, pos, visits)
+                    contrib = tile_push(tiles, tile_dst, sent, pos,
+                                        row_armed)
                 else:
                     row_idx = jnp.arange(f.shape[0])[:, None]
                     msg = sent[row_idx, src_slot] * wgt  # [B_loc, E]
@@ -547,13 +588,14 @@ class DistributedEngine:
                     contrib, jnp.zeros(b_loc * s, contrib.dtype), (my_start,)
                 )
                 obox = obox + contrib
-            return f, h, obox, t_d, ops_d
+            return f, h, obox, t_d, ops_d, armed_d
 
-        def chunk(f, h, outbox, t, pos, ops, rounds, *operands):
+        def chunk(f, h, outbox, t, pos, ops, rounds, armed, *operands):
             """shard_map body.  Per-device shards:
 
             f, h, w, src_slot, ...: [B_loc, S] / [B_loc, E]
-            outbox: [1, R*S]   t: [1]   ops: [1, 2]   pos: [R] replicated
+            outbox: [1, R*S]   t: [1]   ops, armed: [1, 2]
+            pos: [R] replicated
             operands: w, src_slot, dst_bucket, dst_slot, wgt
             [, tiles [B_loc, T, S, S], tile_dst [B_loc, T],
              slot_out_deg [B_loc, S] when bsr]
@@ -563,12 +605,7 @@ class DistributedEngine:
             obox = outbox[0]
             t_d = t[0]
             ops_d = ops[0]
-            # visit tables depend only on tile_dst: compute once per chunk,
-            # not once per round (argsort inside the while_loop body would
-            # not be hoisted by XLA)
-            with jax.named_scope("engine.push"):
-                visits = (_tile_visit_order(operands[6], r_total)
-                          if use_bsr and pallas_path else None)
+            armed_d = armed[0]
             # dangling-slot mask: real node (w != 0) with zero real edges.
             # Loop-invariant given the operands; the bsr path reads it off
             # the prebuilt per-slot degrees, the per-edge path rebuilds
@@ -585,24 +622,24 @@ class DistributedEngine:
             dang = (operands[0] != 0) & (slot_deg == 0)
 
             def body(carry):
-                f, h, obox, t_d, ops_d, i, fire = carry
-                f, h, obox, t_d, ops_d = local_round(
-                    f, h, obox, t_d, ops_d, pos, operands, my_start,
-                    visits, dang)
+                f, h, obox, t_d, ops_d, armed_d, i, fire = carry
+                f, h, obox, t_d, ops_d, armed_d = local_round(
+                    f, h, obox, t_d, ops_d, armed_d, pos, operands,
+                    my_start, dang)
                 with jax.named_scope("engine.residual"):
                     r_k = jnp.sum(jnp.abs(f))
                     s_k = jnp.sum(jnp.abs(obox))
                     fire_local = (s_k > r_k / 2.0).astype(jnp.int32)
                     fire = jax.lax.pmax(fire_local, axis)
-                return f, h, obox, t_d, ops_d, i + 1, fire
+                return f, h, obox, t_d, ops_d, armed_d, i + 1, fire
 
             def cond(carry):
                 *_, i, fire = carry
                 return (i < cfg.max_inner) & (fire == 0)
 
-            f, h, obox, t_d, ops_d, i, _fire = jax.lax.while_loop(
+            f, h, obox, t_d, ops_d, armed_d, i, _fire = jax.lax.while_loop(
                 cond, body,
-                (f, h, obox, t_d, ops_d, jnp.zeros((), jnp.int32),
+                (f, h, obox, t_d, ops_d, armed_d, jnp.zeros((), jnp.int32),
                  jnp.zeros((), jnp.int32)),
             )
             # ---- fluid exchange: reduce-scatter outbox over devices ----
@@ -628,15 +665,15 @@ class DistributedEngine:
                 )
                 obox = jnp.zeros_like(obox)
             return (f, h, obox[None], t_new[None], pos, ops_d[None],
-                    rounds + i)
+                    rounds + i, armed_d[None])
 
         n_operands = 8 if use_bsr else 5
         pr, pp = P(axis), P()
         mapped = shard_map(
             chunk,
             mesh=self.mesh,
-            in_specs=(pr, pr, pr, pr, pp, pr, pp) + (pr,) * n_operands,
-            out_specs=(pr, pr, pr, pr, pp, pr, pp),
+            in_specs=(pr, pr, pr, pr, pp, pr, pp, pr) + (pr,) * n_operands,
+            out_specs=(pr, pr, pr, pr, pp, pr, pp, pr),
             check_vma=False,
         )
 
@@ -647,19 +684,20 @@ class DistributedEngine:
         rep = NamedSharding(self.mesh, pp)
         out_shardings = (
             EngineState(f=row, h=row, outbox=row, t=row, pos_of_bucket=rep,
-                        ops=row, rounds=rep),
+                        ops=row, rounds=rep, armed=row),
             {"r": rep, "s": rep, "residual": rep})
 
         @functools.partial(jax.jit, out_shardings=out_shardings)
         def run_chunk(state: EngineState, *operands):
-            f, h, outbox, t, pos, ops, rounds = (
+            f, h, outbox, t, pos, ops, rounds, armed = (
                 state.f, state.h, state.outbox, state.t,
-                state.pos_of_bucket, state.ops, state.rounds)
+                state.pos_of_bucket, state.ops, state.rounds, state.armed)
             for _ in range(cfg.chunk_rounds):
-                f, h, outbox, t, pos, ops, rounds = mapped(
-                    f, h, outbox, t, pos, ops, rounds, *operands)
+                f, h, outbox, t, pos, ops, rounds, armed = mapped(
+                    f, h, outbox, t, pos, ops, rounds, armed, *operands)
             new = EngineState(f=f, h=h, outbox=outbox, t=t,
-                              pos_of_bucket=pos, ops=ops, rounds=rounds)
+                              pos_of_bucket=pos, ops=ops, rounds=rounds,
+                              armed=armed)
             with jax.named_scope("engine.residual"):
                 stats = {
                     "r": jnp.sum(jnp.abs(f.reshape(cfg.k, -1)), axis=1),
@@ -691,6 +729,7 @@ class DistributedEngine:
                 outbox=row(new_state.outbox), t=row(new_state.t),
                 pos_of_bucket=rep(new_state.pos_of_bucket),
                 ops=row(new_state.ops), rounds=rep(new_state.rounds),
+                armed=row(new_state.armed),
             )
             return new_state, arrs
 

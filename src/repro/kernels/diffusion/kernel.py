@@ -19,13 +19,15 @@ diffusion vectors (e.g. personalized-PageRank columns) share one sweep of
 the sparse structure; ``C = 1`` is the paper's case but wider C raises
 arithmetic intensity from O(1) to O(C) per weight byte.
 
-The fused frontier round (``frontier_round_bsr_pallas``) runs the same
-revisiting-output pattern over a per-round *visit list* instead of the
-whole tile pool: one grid step per tile of an armed block column (one
-holding fluid above threshold), the grid's length being the round's
-visit count, a traced value.  Tiles of unarmed columns cost no step, no
-byte and no MXU issue slot; rows without a visit take the kept fluid in
-the ops.py wrapper.
+Both kernels the solvers run walk a per-round *visit list* instead of
+the whole tile pool, the grid's length being the round's visit count, a
+traced value.  The fused frontier round (``frontier_round_bsr_pallas``)
+visits the tiles of the armed block columns (those holding fluid above
+threshold), and rows without a visit take the kept fluid in the ops.py
+wrapper.  The engine's gather kernel (``bsr_gather_spmm_pallas``) visits
+the tiles of the source rows that send fluid, and the engine masks the
+rows no visit reaches.  Tiles that would multiply zero fluid cost no
+step, no byte and no MXU issue slot.
 
 DMA pipelining (``buffer_depth``): the tile pool is the dominant byte
 stream (``bs*bs`` weights vs ``bs*C`` fluid per step, and C is small).
@@ -34,10 +36,10 @@ double-buffered BlockSpec pipeline.  With ``buffer_depth >= 2`` the tile
 operand stays in HBM (``memory_space=ANY``) and the kernel rotates manual
 async copies through a ``[depth, bs, bs]`` VMEM ring: step ``i`` computes
 out of slot ``i % depth`` while the DMAs for steps ``i+1 .. i+depth-1``
-are already in flight.  In the frontier round every step is a visit, so
-the ring starts and waits one copy a step, and only its warm-up and its
-last ``depth`` refills stop at the visit count.  Both paths execute the
-identical accumulation order, so results are bit-identical across depths
+are already in flight.  Every step is a visit, so the ring starts and
+waits one copy a step, and only its warm-up and its last ``depth``
+refills stop at the visit count.  Both paths execute the identical
+accumulation order, so results are bit-identical across depths
 (test-enforced).
 """
 from __future__ import annotations
@@ -154,15 +156,18 @@ def _gather_kernel(visit_block_ref, visit_row_ref, visit_col_ref,
 
 def _gather_kernel_dma(visit_block_ref, visit_row_ref, visit_col_ref,
                        blocks_hbm_ref, x_ref, o_ref, buf_ref, sem_ref,
-                       *, n_visits: int, depth: int):
+                       *, v: int, depth: int):
     """Manual-DMA twin of :func:`_gather_kernel` (``buffer_depth >= 2``).
 
     The tile pool stays in HBM; a ``[depth, bs, bs]`` VMEM ring holds the
     in-flight gathers.  Step ``i`` waits on slot ``i % depth``, multiplies,
     then immediately reuses the slot to start the copy for step
     ``i + depth`` — so up to ``depth`` tile gathers overlap the MXU work.
+    The warm-up and the refill stop at the grid's length, the visit
+    count, so every started copy is waited exactly once.
     """
     i = pl.program_id(0)
+    n = pl.num_programs(0)
     is_first = i == 0
     new_row = visit_row_ref[i] != visit_row_ref[jnp.maximum(i - 1, 0)]
 
@@ -175,8 +180,10 @@ def _gather_kernel_dma(visit_block_ref, visit_row_ref, visit_col_ref,
 
     @pl.when(is_first)
     def _warmup():
-        for d in range(min(depth, n_visits)):
-            tile_dma(d, d).start()
+        for d in range(min(depth, v)):
+            @pl.when(d < n)
+            def _start(d=d):
+                tile_dma(d, d).start()
 
     @pl.when(jnp.logical_or(is_first, new_row))
     def _init():
@@ -186,11 +193,9 @@ def _gather_kernel_dma(visit_block_ref, visit_row_ref, visit_col_ref,
     tile_dma(slot, i).wait()
     o_ref[0] += _mxu_dot(buf_ref[slot], x_ref[0], o_ref.dtype)
 
-    nxt = jnp.minimum(i + depth, n_visits - 1)
-
-    @pl.when(i + depth < n_visits)
+    @pl.when(i + depth < n)
     def _prefetch():
-        tile_dma(slot, nxt).start()
+        tile_dma(slot, i + depth).start()
 
 
 @functools.partial(
@@ -200,7 +205,7 @@ def _gather_kernel_dma(visit_block_ref, visit_row_ref, visit_col_ref,
 def bsr_gather_spmm_pallas(
     blocks: jax.Array,  # [n_tiles, bs, bs] row-owned tile pool (any order)
     visit_block: jax.Array,  # [V] int32 index into ``blocks``
-    visit_row: jax.Array,  # [V] int32 destination block row, sorted ascending
+    visit_row: jax.Array,  # [V] int32 destination block row of each visit
     visit_col: jax.Array,  # [V] int32 source block col of each visit
     x: jax.Array,  # [n_col_blocks, bs, C]
     n_row_blocks: int,
@@ -208,13 +213,18 @@ def bsr_gather_spmm_pallas(
     bs: int,
     interpret: bool = False,
     buffer_depth: int = 1,
+    n_visits: jax.Array | None = None,  # int32 scalar, traced
 ) -> jax.Array:
     """delta = sum_i blocks[visit_block[i]] @ x[visit_col[i]] into visit_row[i].
 
-    The visit arrays may be computed in-graph (e.g. ``argsort`` of the
-    destination ids each round) — scalar prefetch takes traced values.
-    Rows never visited keep uninitialised garbage; callers mask them with the
-    visit-derived row-occupancy map.
+    The grid has one step per visit, ``i < n_visits``; with ``n_visits``
+    None every one of the V entries is a visit.  The visits must be sorted
+    by ``visit_row``.  Entries from ``n_visits`` on are never read as
+    visits, but they must hold valid indices: a list without visits still
+    runs one step, on entry 0, whose row the caller drops.  The visit
+    arrays may be computed in-graph each round — scalar prefetch takes
+    traced values.  Rows never visited keep uninitialised garbage; callers
+    mask them with the rows the visits reach.
 
     ``buffer_depth`` selects the tile-fetch strategy: 1 = automatic BlockSpec
     pipelining, >= 2 = a manual ``depth``-deep async-copy ring (see module
@@ -225,10 +235,11 @@ def bsr_gather_spmm_pallas(
     v = visit_block.shape[0]
     c = x.shape[-1]
     out_shape = jax.ShapeDtypeStruct((n_row_blocks, bs, c), x.dtype)
+    grid = (v,) if n_visits is None else (jnp.maximum(n_visits, 1),)
     if buffer_depth == 1:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # visit_block, visit_row, visit_col
-            grid=(v,),
+            grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bs, bs), lambda i, vb, vr, vc: (vb[i], 0, 0)),
                 pl.BlockSpec((1, bs, c), lambda i, vb, vr, vc: (vc[i], 0, 0)),
@@ -241,7 +252,7 @@ def bsr_gather_spmm_pallas(
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(v,),
+            grid=grid,
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),  # tile pool stays in HBM
                 pl.BlockSpec((1, bs, c), lambda i, vb, vr, vc: (vc[i], 0, 0)),
@@ -254,9 +265,7 @@ def bsr_gather_spmm_pallas(
                 pltpu.SemaphoreType.DMA((buffer_depth,)),
             ],
         )
-        body = functools.partial(
-            _gather_kernel_dma, n_visits=v, depth=buffer_depth
-        )
+        body = functools.partial(_gather_kernel_dma, v=v, depth=buffer_depth)
     fn = pl.pallas_call(
         body,
         grid_spec=grid_spec,

@@ -424,12 +424,13 @@ def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
         pos_of_bucket=rowi(r),
         ops=sds((k, 2), jnp.uint32),
         rounds=sds((), jnp.int32),
+        armed=sds((k, 2), jnp.uint32),
     )
     sh = lambda spec: NamedSharding(flat_mesh, spec)
     state_sh = EngineState(
         f=sh(P("pid")), h=sh(P("pid")), outbox=sh(P("pid")),
         t=sh(P("pid")), pos_of_bucket=sh(P()), ops=sh(P("pid")),
-        rounds=sh(P()),
+        rounds=sh(P()), armed=sh(P("pid")),
     )
     args = (state, row(r, bucket_size), rowi(r, edge_cap),
             rowi(r, edge_cap), rowi(r, edge_cap), row(r, edge_cap))

@@ -8,6 +8,8 @@ host-ordered crawl whose hosts are sorted by out-links (``popsort``).
   and the invariant B = (I - P) H + F with F holding the outboxes, on the
   einsum tile path and on the Pallas gather kernel (interpret mode);
 * the push count: two uint32 words a device, exact past 2**31;
+* the armed-tile tally: T tiles for each bucket row holding a selected
+  node, each local round, banked across a warm start;
 * the jitted chunk: compiled once, found again after a kept state is put
   back;
 * what it records: ``repro.*`` host spans around the chunk's dispatch, the
@@ -170,6 +172,53 @@ def test_push_count_is_exact_past_2_31(problem):
     assert twin_rep.n_ops > 1000
     assert rep.n_ops == s.n_ops == start + twin_rep.n_ops > 2**32
     assert isinstance(rep.n_ops, int)
+
+
+# ---- the armed-tile tally ---------------------------------------------------
+def _numpy_armed(d) -> int:
+    """T times the bucket rows holding a node the next local round
+    selects, counted in numpy from the state before it (k = 1)."""
+    st = d.ex.state
+    sel = np.abs(np.asarray(st.f)) * np.asarray(d.ex.w) > \
+        np.asarray(st.t)[0]
+    return d.arrays.tile_dst.shape[1] * int(sel.any(axis=1).sum())
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["einsum", "pallas_interpret"])
+def test_armed_tiles_match_a_numpy_count(problem, interpret):
+    """One local round a grain: after each, the tally holds the tiles of
+    the rows with a selected node, summed over the rounds so far."""
+    s = _session(problem, chunk_rounds=1, max_inner=1, interpret=interpret)
+    d = s._driver
+    it = s.run()
+    expected = 0
+    for _ in range(6):
+        expected += _numpy_armed(d)
+        next(it)
+        assert s.n_armed_tiles == expected
+    assert s.n_rounds == 6
+    assert 0 < expected < s.n_rounds * d.arrays.tile_dst.size
+
+
+def test_armed_tiles_bank_across_warm_start(problem):
+    s = _session(problem)
+    s.solve(until=1e-2)
+    first = s.n_armed_tiles
+    assert 0 < first < s.n_rounds * s._driver.arrays.tile_dst.size
+    assert s.lifetime_armed_tiles == first
+    s.warm_start(np.asarray(problem.b) * 1.01)
+    assert s.n_armed_tiles == 0 and s.lifetime_armed_tiles == first
+    s.solve(until=1e-2)
+    second = s.n_armed_tiles
+    assert second > 0
+    assert s.lifetime_armed_tiles == first + second
+
+
+def test_the_per_edge_engine_has_no_armed_tiles(problem):
+    s = repro.SolverSession(problem, method="engine:chunk", k=1)
+    next(s.run())
+    assert s.n_armed_tiles is None and s.lifetime_armed_tiles is None
 
 
 # ---- the jitted chunk -------------------------------------------------------

@@ -304,6 +304,49 @@ def test_gather_spmm_depth_exceeds_visits():
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("n_visits", [0, 1, 11, 24], ids=[
+    "none", "one", "some", "all"])
+def test_gather_spmm_traced_visit_count(n_visits, depth):
+    """With a traced ``n_visits`` the grid runs over the first ``n_visits``
+    entries of the visit list only: every row they reach is bit-identical
+    to the depth-1 kernel over just those entries, at either depth.  The
+    entries after them (valid indices, sorted on their own, as the engine
+    lays them out) add nothing.  With no visit the single step the grid
+    still runs touches entry 0's row only, which the caller masks."""
+    bs = 16
+    rng = np.random.default_rng(5)
+    n_tiles, nrb = 24, 6
+    pool = rng.standard_normal((n_tiles, bs, bs)).astype(np.float32) * 0.1
+    dst = rng.integers(0, nrb, n_tiles).astype(np.int32)
+    x = rng.standard_normal((nrb, bs, 1)).astype(np.float32)
+    # the armed entries first, then the rest, each sorted by destination
+    armed = np.zeros(n_tiles, bool)
+    armed[rng.permutation(n_tiles)[:n_visits]] = True
+    order = np.lexsort((dst, ~armed)).astype(np.int32)
+    col = order % nrb
+    lists = (jnp.asarray(order), jnp.asarray(dst[order]), jnp.asarray(col))
+    out = np.asarray(bsr_gather_spmm_pallas(
+        jnp.asarray(pool), *lists, jnp.asarray(x), nrb, bs=bs,
+        interpret=True, buffer_depth=depth, n_visits=jnp.int32(n_visits)))
+    first = max(n_visits, 1)
+    ref = np.asarray(bsr_gather_spmm_pallas(
+        jnp.asarray(pool), *(a[:first] for a in lists), jnp.asarray(x),
+        nrb, bs=bs, interpret=True, buffer_depth=1))
+    reached = np.unique(dst[order[:first]])
+    assert np.array_equal(out[reached], ref[reached])
+    if n_visits == 0:
+        assert reached.tolist() == [dst[order[0]]]
+        np.testing.assert_allclose(out[reached[0]], pool[order[0]]
+                                   @ x[col[0]], rtol=2e-5, atol=2e-5)
+    else:
+        expect = np.zeros((nrb, bs, 1), np.float32)
+        for i in order[:n_visits]:
+            expect[dst[i]] += pool[i] @ x[i % nrb]
+        np.testing.assert_allclose(out[reached], expect[reached],
+                                   rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("depth", [2, 4])
 def test_dma_kernels_trace_under_x64_with_f32_data(depth):
     """The DMA rings index their slot with an int32 ``rem`` even when

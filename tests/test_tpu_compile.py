@@ -80,8 +80,10 @@ def test_frontier_kernel_compiles_for_v5e(one_chip, c, depth):
     assert _hlo_has_kernel(compiled)
 
 
+@pytest.mark.parametrize("traced_grid", [False, True],
+                         ids=["every_entry", "n_visits"])
 @pytest.mark.parametrize("depth", [1, 2, 4])
-def test_gather_kernel_compiles_for_v5e(one_chip, depth):
+def test_gather_kernel_compiles_for_v5e(one_chip, depth, traced_grid):
     s, r_total, n_tiles = 128, 64, 320
     i32 = jnp.int32
     args = (
@@ -91,10 +93,18 @@ def test_gather_kernel_compiles_for_v5e(one_chip, depth):
         _spec((n_tiles,), i32, one_chip),
         _spec((r_total, s, 1), jnp.float32, one_chip),
     )
-    fn = functools.partial(bsr_gather_spmm_pallas, n_row_blocks=r_total,
-                           bs=s, buffer_depth=depth)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert _hlo_has_kernel(compiled)
+    if traced_grid:
+        def fn(*a, n_visits):
+            return bsr_gather_spmm_pallas(
+                *a, n_row_blocks=r_total, bs=s, buffer_depth=depth,
+                n_visits=n_visits)
+
+        lowered = jax.jit(fn).lower(*args, n_visits=_spec((), i32, one_chip))
+    else:
+        lowered = jax.jit(functools.partial(
+            bsr_gather_spmm_pallas, n_row_blocks=r_total, bs=s,
+            buffer_depth=depth)).lower(*args)
+    assert _hlo_has_kernel(lowered.compile())
 
 
 def test_frontier_round_at_2_21_fits_one_v5e(one_chip):
@@ -170,9 +180,12 @@ def test_engine_chunk_at_2_21_fits_one_v5e(one_chip, monkeypatch):
     crawl: R = 16,386 bucket rows of S = 128 (2^21 / 128 real buckets and
     2 of headroom), T = 5 tiles a row, E = 8,958 edge slots a row (the
     largest bucket's links).  The gather kernel is in the program, it keeps
-    the name the benchmark's reader matches, the chunk's stages carry their
-    ``engine.*`` named scopes, and its arguments and temporaries fit one
-    v5e."""
+    the name the benchmark's reader matches, its grid's length is a traced
+    operand (the round's armed visits) beside the same three prefetched
+    lists of R·T slots, the chunk's stages carry their ``engine.*`` named
+    scopes, and its arguments and temporaries fit one v5e."""
+    import re
+
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -203,7 +216,7 @@ def test_engine_chunk_at_2_21_fits_one_v5e(one_chip, monkeypatch):
         f=spec((r, s), f32), h=spec((r, s), f32),
         outbox=spec((1, r * s), f32), t=spec((1,), f32),
         pos_of_bucket=spec((r,), i32, rep), ops=spec((1, 2), u32),
-        rounds=spec((), i32, rep))
+        rounds=spec((), i32, rep), armed=spec((1, 2), u32))
     operands = (spec((r, s), f32), spec((r, e), i32), spec((r, e), i32),
                 spec((r, e), i32), spec((r, e), f32),
                 spec((r, t, s, s), f32), spec((r, t), i32),
@@ -212,6 +225,13 @@ def test_engine_chunk_at_2_21_fits_one_v5e(one_chip, monkeypatch):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "%bsr_gather_spmm_pallas" in text
+    kernels = re.findall(r"%bsr_gather_spmm_pallas[.\d]* = .*custom-call\(.*",
+                         text)
+    assert kernels
+    slots = f"s32[{r * t}]{{0}}"
+    for line in kernels:
+        assert (f"operand_layout_constraints={{s32[], {slots}, {slots}, "
+                f"{slots}, f32[{r * t},") in line, line[:300]
     for stage in ("select", "push", "exchange", "residual"):
         assert f"/engine.{stage}/" in text, stage
     mem = compiled.memory_analysis()
