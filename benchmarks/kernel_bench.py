@@ -2,10 +2,10 @@
 
 Two parts:
 
-* :func:`main` — wall time of the XLA reference paths on CPU (the Pallas
-  kernels are TPU-target and validated in interpret mode — CPU interpret
-  timings are not meaningful) + derived figures (bytes, flops, arithmetic
-  intensity) used in the roofline discussion.  Prints
+* :func:`main` — wall time of the XLA reference diffusion path on CPU
+  (the Pallas kernels are TPU-target and validated in interpret mode —
+  CPU interpret timings are not meaningful) + derived figures (edges,
+  blocks, arithmetic intensity) used in the roofline discussion.  Prints
   ``name,us_per_call,derived`` CSV as required.
 
 * :func:`frontier_sweep` — the solver hot path head-to-head: one frontier
@@ -40,7 +40,6 @@ from benchmarks._meta import std_meta
 from benchmarks.roofline import annotate_payload
 
 from repro.core import host_block_graph, pagerank_system, power_law_graph
-from repro.kernels.attention import attention_ref
 from repro.kernels.diffusion import (
     BsrMatrix,
     bsr_spmm,
@@ -51,8 +50,6 @@ from repro.kernels.diffusion import (
     frontier_round_ref,
     prepare_bsr,
 )
-from repro.kernels.fm import fm_interaction_ref
-from repro.kernels.segment import segment_sum_ref
 
 
 def timeit(fn, *args, iters=20):
@@ -309,33 +306,6 @@ def main():
     ai = 2 * g.n_edges / (m.n_blocks * 128 * 128 * 4)
     rows.append(("diffusion_bsr_ref_N20k", us,
                  f"edges={g.n_edges};blocks={m.n_blocks};ai={ai:.3f}"))
-
-    # segment-sum: 1M edges x 64 feat
-    e, d, s = 1_000_000, 64, 100_000
-    seg = jnp.asarray(np.sort(rng.integers(0, s, e)).astype(np.int32))
-    data = jnp.asarray(rng.standard_normal((e, d)).astype(np.float32))
-    us = timeit(lambda a, b: segment_sum_ref(a, b, s), data, seg)
-    rows.append(("segment_sum_ref_1Mx64", us,
-                 f"bytes={e*d*4*2};gbps={e*d*4*2/us/1e3:.2f}"))
-
-    # fm: criteo-shaped batch
-    v = jnp.asarray(rng.standard_normal((65536, 39, 10)).astype(np.float32))
-    us = timeit(fm_interaction_ref, v)
-    rows.append(("fm_interaction_ref_B65536", us,
-                 f"bytes={v.size*4};gbps={v.size*4/us/1e3:.2f}"))
-
-    # attention: 1 head-group block
-    q = jnp.asarray(rng.standard_normal((1, 8, 1024, 128))
-                    .astype(np.float32) * 0.1)
-    k = jnp.asarray(rng.standard_normal((1, 2, 1024, 128))
-                    .astype(np.float32) * 0.1)
-    vv = jnp.asarray(rng.standard_normal((1, 2, 1024, 128))
-                     .astype(np.float32))
-    us = timeit(lambda q, k, v: attention_ref(q, k, v, causal=True),
-                q, k, vv)
-    fl = 4 * 8 * 1024 * 1024 * 128
-    rows.append(("attention_ref_1x8x1024x128", us,
-                 f"flops={fl};gflops={fl/us/1e3:.1f}"))
 
     print("name,us_per_call,derived")
     for n, us, d in rows:

@@ -7,7 +7,7 @@ granularity.  This package is that claim turned into an architecture:
 
 * :class:`~repro.balance.signals.LoadSignal` — the one measurement
   container every layer produces (per-PID residuals, per-device edge-op
-  counts, per-host step wall-times, per-expert token counts).
+  counts, per-worker graph churn, serving latency and queue pressure).
 * :class:`~repro.balance.policies.Rebalancer` — the policy protocol:
   ``propose(LoadSignal) -> [MovePlan]`` + ``reset_worker(k)``.  Three
   implementations ship: :class:`SlopeEMAPolicy` (paper §2.5.2 exact),
@@ -18,17 +18,16 @@ granularity.  This package is that claim turned into an architecture:
   signal).
 * :class:`~repro.balance.plan.MovePlan` — granularity-agnostic
   "move ``units`` from worker ``src`` to worker ``dst``" decision with a
-  declared unit kind (``node`` | ``bucket`` | ``expert-shard`` |
-  ``device``).
+  declared unit kind (``node`` | ``bucket``).
 * :mod:`~repro.balance.executors` — per-granularity executors that turn
   a MovePlan into actual state mutation: node moves in the faithful
-  simulator (with the §2.4 reassignment-cost charging), bucket-row
-  permutations in the distributed engine, and an advisory recorder for
-  the runtime's straggler / MoE paths.
+  simulator (with the §2.4 reassignment-cost charging) and bucket-row
+  permutations in the distributed engine.
 
-Consumers: :mod:`repro.core.simulator` (node-granular),
-:mod:`repro.core.distributed` (bucket-granular),
-:mod:`repro.runtime.loop` (device- and expert-granular).
+Consumers: :mod:`repro.core.simulator` (node-granular) and
+:mod:`repro.core.distributed` (bucket-granular); the serving scheduler
+and the resilience supervisor feed ``queue-depth`` / ``latency``
+signals to :class:`PressurePolicy`.
 """
 from .plan import MovePlan
 from .signals import LoadSignal
@@ -41,7 +40,6 @@ from .policies import (
     make_rebalancer,
 )
 from .executors import (
-    AdvisoryExecutor,
     BucketMoveExecutor,
     MoveExecutor,
     NodeMoveExecutor,
@@ -59,5 +57,4 @@ __all__ = [
     "MoveExecutor",
     "NodeMoveExecutor",
     "BucketMoveExecutor",
-    "AdvisoryExecutor",
 ]

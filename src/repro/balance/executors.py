@@ -14,14 +14,10 @@ exist), so policies/consumers can account moves truthfully.
   engine: plans a row permutation onto the destination device's inert
   headroom rows and applies it in-graph (``jnp.take`` on the sharded
   axis).
-* :class:`AdvisoryExecutor` — records plans without acting; the
-  runtime's straggler monitor and the MoE expert rebalancer run in this
-  mode inside a single process (on a pod the log drives the bucket /
-  expert-shard movers).
 """
 from __future__ import annotations
 
-from typing import List, Protocol, runtime_checkable
+from typing import Protocol, runtime_checkable
 
 import numpy as np
 
@@ -31,7 +27,6 @@ __all__ = [
     "MoveExecutor",
     "NodeMoveExecutor",
     "BucketMoveExecutor",
-    "AdvisoryExecutor",
 ]
 
 
@@ -159,23 +154,3 @@ class BucketMoveExecutor:
             self.tiles, self.tile_dst, self.slot_out_deg = arrs[5:8]
         return moved
 
-
-class AdvisoryExecutor:
-    """Records plans without acting (single-process runtime mode).
-
-    ``log`` keeps every accepted plan; ``drain()`` hands them to
-    whatever actually migrates load (bucket mover, expert-shard
-    re-placer) and clears the log.
-    """
-
-    def __init__(self, kind: str = "device"):
-        self.kind = kind
-        self.log: List[MovePlan] = []
-
-    def apply(self, plan: MovePlan) -> int:
-        self.log.append(plan)
-        return plan.units
-
-    def drain(self) -> List[MovePlan]:
-        out, self.log = self.log, []
-        return out

@@ -2,8 +2,8 @@
 
 A rebalancing policy never sees what a "unit" is — it emits
 :class:`MovePlan`\\ s, and a per-granularity executor (see
-:mod:`repro.balance.executors`) turns them into node reassignments,
-bucket-row permutations, or expert-shard migrations.
+:mod:`repro.balance.executors`) turns them into node reassignments or
+bucket-row permutations.
 """
 from __future__ import annotations
 
@@ -11,7 +11,7 @@ import dataclasses
 
 __all__ = ["MovePlan", "UNIT_KINDS"]
 
-UNIT_KINDS = ("node", "bucket", "expert-shard", "device")
+UNIT_KINDS = ("node", "bucket")
 
 
 @dataclasses.dataclass(frozen=True)

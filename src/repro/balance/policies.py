@@ -3,7 +3,7 @@
 All policies implement the :class:`Rebalancer` protocol —
 ``propose(LoadSignal) -> list[MovePlan]`` once per control step plus
 ``reset_worker(k)`` for elastic events — and are deliberately ignorant
-of what a load unit is (node, bucket, expert shard, device slice).
+of what a load unit is (a node or a bucket).
 
 * :class:`SlopeEMAPolicy` — the paper's §2.5.2 controller, verbatim: it
   wraps :class:`repro.core.partition.DynamicController` so decisions

@@ -10,8 +10,6 @@ kind            values[k]                              unit
 ==============  =====================================  ==============
 residual        r_k + s_k (fluid left + in flight)     node / bucket
 edge-ops        edge operations charged this window    node / bucket
-step-time       wall-clock seconds of worker k's step  device
-expert-tokens   tokens routed to expert shard k        expert-shard
 graph-churn     changed edges owned by worker k        node / bucket
 latency         serving pressure (deadline + queue)    request stream
 ==============  =====================================  ==============
@@ -24,14 +22,13 @@ slope and sheds load).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
 
 import numpy as np
 
 __all__ = ["LoadSignal", "SIGNAL_KINDS"]
 
-SIGNAL_KINDS = ("residual", "edge-ops", "step-time", "expert-tokens",
-                "graph-churn", "latency", "queue-depth")
+SIGNAL_KINDS = ("residual", "edge-ops", "graph-churn", "latency",
+                "queue-depth")
 
 
 @dataclasses.dataclass
@@ -42,7 +39,7 @@ class LoadSignal:
     ``sizes`` — [K] load units currently owned by each worker;
     ``kind`` — which measurement produced ``values``;
     ``step`` — producer's control-step counter (simulator time step,
-    engine chunk index, runtime step).
+    engine chunk index).
     """
 
     values: np.ndarray
@@ -84,24 +81,6 @@ class LoadSignal:
                    kind="edge-ops", step=step)
 
     @classmethod
-    def from_step_times(cls, seconds: np.ndarray,
-                        load_units: Optional[np.ndarray] = None,
-                        step: int = 0) -> "LoadSignal":
-        """Per-host step wall-times (a straggler is a slow PID).
-
-        Times are normalized to fractions of the total so the slope
-        policies see residual-like magnitudes in (0, 1) — the §2.5.2
-        move-fraction formula ``(slope_min+1)/(slope_max+1)`` assumes the
-        signal exponent is negative, and fractions make the signal
-        independent of the absolute step duration.
-        """
-        seconds = np.maximum(np.asarray(seconds, np.float64), 1e-9)
-        if load_units is None:
-            load_units = np.full(seconds.shape[0], 1 << 20)
-        return cls(values=seconds / seconds.sum(), sizes=load_units,
-                   kind="step-time", step=step)
-
-    @classmethod
     def from_graph_churn(cls, churn_counts: np.ndarray,
                          sizes: np.ndarray, step: int = 0) -> "LoadSignal":
         """Changed-edge counts per worker after a graph delta.
@@ -110,7 +89,11 @@ class LoadSignal:
         *and* re-diffuses the injected fluid ``(P'−P)·H`` — the paper's
         thesis applied to graph drift: the controller needs only this
         magnitude, no structural analysis.  Counts are normalized to
-        fractions (see :meth:`from_step_times` for why).
+        fractions so the slope policies see residual-like magnitudes in
+        (0, 1) — the §2.5.2 move-fraction formula
+        ``(slope_min+1)/(slope_max+1)`` assumes the signal exponent is
+        negative, and fractions make the signal independent of the
+        absolute churn volume.
         """
         churn = np.maximum(np.asarray(churn_counts, np.float64), 0.0)
         total = churn.sum()
@@ -171,20 +154,3 @@ class LoadSignal:
         return cls(values=np.array([pressure]),
                    sizes=np.array([max(int(queue_depth), 0)]),
                    kind="queue-depth", step=step)
-
-    @classmethod
-    def from_expert_counts(cls, token_counts: np.ndarray,
-                           shards_per_expert: Optional[np.ndarray] = None,
-                           step: int = 0) -> "LoadSignal":
-        """Per-expert routed-token counts (a hot expert is a hot Ω_k).
-
-        Counts are normalized to routing fractions (see
-        :meth:`from_step_times` for why).
-        """
-        token_counts = np.maximum(
-            np.asarray(token_counts, np.float64), 1e-12)
-        return cls(values=token_counts / token_counts.sum(),
-                   sizes=(shards_per_expert if shards_per_expert is not None
-                          else np.ones(token_counts.shape[0],
-                                       dtype=np.int64)),
-                   kind="expert-tokens", step=step)

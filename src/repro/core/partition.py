@@ -13,16 +13,13 @@ Three strategies:
   the whole point of the paper is that load balance emerges from the
   *observed* residual decay rates alone.
 
-The controller is reused at three levels of the system through the
-:mod:`repro.balance` control plane (DESIGN.md §5/§6), where it is wrapped
+The controller is reused at two levels of the system through the
+:mod:`repro.balance` control plane (DESIGN.md §5), where it is wrapped
 as ``SlopeEMAPolicy`` and its decisions travel as granularity-agnostic
 ``MovePlan``\\ s:
 
 1. node-granular in the faithful simulator (paper-exact reproduction),
-2. bucket-granular in the production distributed solver (static shapes),
-3. device-granular in the runtime as a straggler/elastic policy (a
-   straggling host is exactly a "slow PID") and expert-granular as the
-   MoE rebalancer (a hot expert is exactly an overloaded Ω_k).
+2. bucket-granular in the production distributed solver (static shapes).
 
 This module keeps only the paper-exact primitives (§2.5.1 static
 partitions, the §2.5.2 slope-EMA update, :func:`apply_move`); policy
@@ -133,8 +130,8 @@ class DynamicController:
     """Slope-EMA load balancer (paper §2.5.2), unit-agnostic.
 
     Feed it the per-PID residual magnitude ``r_k + s_k`` (or any positive
-    per-worker progress signal: per-expert token counts, per-device step
-    times) once per time step together with the current per-PID set sizes;
+    per-worker progress signal: edge operations charged, changed edges
+    owned) once per time step together with the current per-PID set sizes;
     it returns a :class:`MoveInstruction` when the imbalance rule fires.
 
     Paper-exact update::

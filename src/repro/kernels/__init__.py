@@ -1,21 +1,17 @@
-"""Pallas TPU kernels for the system's compute hot-spots.
-
-Each kernel family is a subpackage with three modules:
-
-* ``kernel.py`` — the ``pl.pallas_call`` + ``BlockSpec`` TPU kernel,
-* ``ops.py``    — the jit'd public wrapper (auto-interpret on CPU),
-* ``ref.py``    — the pure-jnp oracle used by tests and as the XLA fallback.
-
-Families (DESIGN.md §3):
+"""Pallas TPU kernels for the solver's hot loop (DESIGN.md §3).
 
 * ``diffusion`` — block-sparse (BSR) fluid push: the D-iteration hot loop
   recast as dense [bs x bs] tile matmuls on the MXU (the TPU-native
-  replacement for the paper's scalar scatter push).
-* ``segment``   — two-stage sorted segment-sum (one-hot-matmul partials +
-  cheap block add): GNN message passing and embedding-bag gather-reduce.
-* ``fm``        — factorization-machine pairwise interaction via the
-  O(nk) sum-square trick, fused over batch tiles.
-* ``attention`` — blockwise causal flash attention with GQA for the LM
-  architectures (online softmax, VMEM accumulators).
+  replacement for the paper's scalar scatter push).  Three modules:
+
+  * ``kernel.py`` — the ``pl.pallas_call`` + ``BlockSpec`` TPU kernels,
+  * ``ops.py``    — the jit'd public wrappers (auto-interpret on CPU),
+  * ``ref.py``    — the pure-jnp oracle used by tests and as the XLA
+    fallback.
+
+* ``tune`` — autotuning for those kernels: ``run_sweep`` measures the
+  kernel knobs (bs, buffer_depth, occupancy threshold) on the current
+  platform and records the winner, which ``solve(method="auto")`` and
+  the session drivers read back.
 """
-from . import diffusion, segment, fm, attention  # noqa: F401
+from . import diffusion  # noqa: F401

@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve/solve drivers."""
+"""Launchers: solve and serve drivers, the engine's mesh and dry run."""

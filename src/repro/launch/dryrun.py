@@ -1,26 +1,21 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+"""Multi-pod dry-run: lower + compile the paper's engine chunk.
 
-"""Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
-
-For each cell this proves on 512 placeholder devices that the sharding
-config is coherent: ``jax.jit(step, in_shardings=...).lower(...).compile()``
-must succeed, fit memory, and produce the cost/collective numbers the
-roofline analysis (benchmarks/roofline.py) consumes.
+Proves on 256 / 512 placeholder devices that the engine's ``shard_map``
+chunk is coherent on a production mesh: ``jax.jit(...).lower(...)
+.compile()`` must succeed, and the compiled HLO yields the memory, cost
+and per-device collective inventory (``parse_collectives``) recorded in
+the output JSON.
 
 Usage:
-  python -m repro.launch.dryrun --arch qwen1.5-0.5b --cell train_4k --mesh single
-  python -m repro.launch.dryrun --all            # every cell × both meshes
-  python -m repro.launch.dryrun --solver         # the paper's engine entry
+  python -m repro.launch.dryrun --solver [--mesh single|multi|both]
 
-Outputs: results/dryrun/<arch>__<cell>__<mesh>.json
+Outputs: results/dryrun/solver__chunk__<mesh>.json
 """
 import argparse
 import json
+import os
 import re
-import sys
 import time
-import traceback
 
 import jax
 import numpy as np
@@ -49,7 +44,6 @@ _COMP_HDR_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(")
 _WHILE_RE = re.compile(
     r"while\(.*?\),\s*condition=%?([\w.\-]+),\s*body=%?([\w.\-]+)")
 _CALL_RE = re.compile(r"to_apply=%?([\w.\-]+)")
-_CONST_RE = re.compile(r"constant\((\d+)\)")
 
 
 def _split_computations(hlo_text: str):
@@ -87,9 +81,9 @@ def _while_trips(line: str, trip_candidates) -> int:
 
     A lax.scan of length T stacks its xs/ys with leading dim T; the while
     op's printed result tuple exposes those leading dims.  We vote among
-    the candidate trip counts for this loop's NESTING DEPTH (stacked layer
-    params thread through outer loops too, so depth-blind voting
-    mis-attributes the microbatch loop to the layer count).
+    the candidate trip counts for this loop's NESTING DEPTH (arrays
+    stacked for an inner scan thread through outer loops too, so
+    depth-blind voting mis-attributes an outer loop to the inner count).
     """
     if not trip_candidates:
         return 1
@@ -106,12 +100,12 @@ def _while_trips(line: str, trip_candidates) -> int:
 def _loop_multipliers(comps, trip_candidates=()):
     """Multiplier per computation = product of enclosing while trip counts.
 
-    XLA:CPU's cost_analysis counts while bodies ONCE (verified in
-    EXPERIMENTS.md §Dry-run), so the collective inventory must re-apply
-    the trip counts.  ``trip_candidates`` is either a flat set (depth-blind)
-    or a list of per-depth sets: ``[ {outermost trips}, {depth-1 trips},
-    ... ]`` — a while at nesting depth d only votes within candidates[d]
-    (falling back to the last entry for deeper loops).
+    XLA:CPU's cost_analysis counts while bodies ONCE, so the collective
+    inventory must re-apply the trip counts.  ``trip_candidates`` is
+    either a flat set (depth-blind) or a list of per-depth sets:
+    ``[ {outermost trips}, {depth-1 trips}, ... ]`` — a while at nesting
+    depth d only votes within candidates[d] (falling back to the last
+    entry for deeper loops).
     """
     if trip_candidates and isinstance(trip_candidates, (set, frozenset)):
         by_depth = [set(trip_candidates)]
@@ -157,9 +151,8 @@ def parse_collectives(hlo_text: str, trip_candidates=()):
     """Loop-aware per-device collective inventory from post-SPMD HLO.
 
     Each collective record carries ``trips`` — the product of enclosing
-    while-loop trip counts (scan-over-layers × microbatch scan × ...) —
-    and ``moved_bytes`` already scaled by it.  ``trip_candidates`` are the
-    known scan lengths of the lowered cell (layers, microbatches, chunks).
+    while-loop trip counts — and ``moved_bytes`` already scaled by it.
+    ``trip_candidates`` are the known scan lengths of the lowered program.
 
     Bytes-moved estimate per op (ring algorithms, per participating device):
       all-reduce:        2·b·(g-1)/g      (b = result bytes)
@@ -224,142 +217,6 @@ def classify_link(rec, n_single_pod=256):
     return "ici"
 
 
-def lower_cell(arch_id: str, cell_name: str, multi_pod: bool,
-               out_dir: str = None, verbose: bool = True):
-    from repro.configs import get_arch
-    from repro.launch.mesh import make_production_mesh, mesh_rules
-    from repro.launch.steps import build_cell_step
-    from repro.parallel.axes import axis_rules
-
-    spec = get_arch(arch_id)
-    cell = spec.cells[cell_name]
-    if cell.skip:
-        return {"arch": arch_id, "cell": cell_name, "skipped": cell.skip}
-    mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
-    if cell.meta.get("mesh_only") and cell.meta["mesh_only"] != mesh_name:
-        return {"arch": arch_id, "cell": cell_name,
-                "skipped": f"mesh_only={cell.meta['mesh_only']}"}
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    rules = {**mesh_rules(multi_pod), **spec.rules_override,
-             **cell.rules_override}
-    dp = 1
-    batch_axes = rules.get("batch")
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if isinstance(batch_axes, tuple):
-        for a in batch_axes:
-            dp *= sizes.get(a, 1)
-    elif batch_axes:
-        dp = sizes.get(batch_axes, 1)
-
-    t0 = time.time()
-    with axis_rules(rules, mesh=mesh):
-        step, args, in_specs = build_cell_step(spec, cell, rules,
-                                               dp_shards=dp,
-                                               axis_sizes=sizes)
-        from jax.sharding import NamedSharding
-
-        in_shardings = jax.tree.map(
-            lambda s: NamedSharding(mesh, s), in_specs,
-            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
-        )
-        # train: donate params+opt; decode: donate the batch (cache
-        # buffers alias their updated outputs — in-place KV update)
-        donate = ((0, 1) if cell.kind == "train"
-                  else (1,) if cell.kind == "decode" else ())
-        with mesh:
-            jitted = jax.jit(step, in_shardings=in_shardings,
-                             donate_argnums=donate)
-            lowered = jitted.lower(*args)
-            t_lower = time.time() - t0
-            compiled = lowered.compile()
-            t_compile = time.time() - t0 - t_lower
-
-    mem = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
-    hlo = compiled.as_text()
-    # known scan lengths of this cell -> while trip counts per nesting
-    # depth (see parse_collectives; XLA:CPU cost analysis counts loop
-    # bodies once, and stacked layer params thread through outer loops,
-    # so candidates must be depth-indexed)
-    trip_candidates = []
-    if spec.family == "lm":
-        import dataclasses as _dc
-
-        from repro.launch.steps import effective_overrides
-
-        ov = effective_overrides(spec, cell, dp)
-        cfg_eff = (_dc.replace(spec.model_cfg, **ov) if ov
-                   else spec.model_cfg)
-        l = cfg_eff.n_layers
-        nm = cfg_eff.n_microbatches
-        seq = cell.meta.get("seq", 0)
-        n_ce = (seq // cfg_eff.ce_chunk
-                if (cell.kind == "train" and seq
-                    and seq // cfg_eff.ce_chunk > 1) else 0)
-        n_attn = (seq // cfg_eff.attn_q_chunk
-                  if (cfg_eff.attn_q_chunk and seq
-                      and cell.kind in ("train", "prefill")) else 0)
-        inner = {l} | ({n_ce} if n_ce else set()) \
-            | ({n_attn} if n_attn else set())
-        if cell.kind == "train" and nm > 1:
-            trip_candidates = [{nm}, inner,
-                               ({n_ce} if n_ce else set())
-                               | ({n_attn} if n_attn else set())]
-        else:
-            trip_candidates = [inner,
-                               ({n_ce} if n_ce else set())
-                               | ({n_attn} if n_attn else set())]
-    colls = parse_collectives(hlo, trip_candidates)
-    for c in colls:
-        c["link"] = classify_link(c)
-    result = {
-        "arch": arch_id,
-        "cell": cell_name,
-        "mesh": "pod2x16x16" if multi_pod else "pod16x16",
-        "n_devices": int(np.prod(mesh.devices.shape)),
-        "lower_s": round(t_lower, 2),
-        "compile_s": round(t_compile, 2),
-        "memory": {
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
-        },
-        "cost": {
-            "flops_per_device": ca.get("flops"),
-            "bytes_per_device": ca.get("bytes accessed"),
-        },
-        "collectives": {
-            "count": len(colls),
-            "moved_bytes_total": sum(c["moved_bytes"] for c in colls),
-            "moved_bytes_ici": sum(
-                c["moved_bytes"] for c in colls if c["link"] == "ici"),
-            "moved_bytes_dcn": sum(
-                c["moved_bytes"] for c in colls if c["link"] == "dcn"),
-            "by_op": _by_op(colls),
-            "records": colls[:200],
-        },
-        "meta": cell.meta,
-        "kind": cell.kind,
-    }
-    if out_dir:
-        os.makedirs(out_dir, exist_ok=True)
-        fname = f"{arch_id}__{cell_name}__{result['mesh']}.json"
-        with open(os.path.join(out_dir, fname), "w") as f:
-            json.dump(result, f, indent=1)
-    if verbose:
-        mm = result["memory"]
-        print(
-            f"[OK] {arch_id} × {cell_name} × {result['mesh']}: "
-            f"args={_gb(mm['argument_bytes'])} temp={_gb(mm['temp_bytes'])} "
-            f"flops/dev={result['cost']['flops_per_device']:.3e} "
-            f"colls={result['collectives']['count']} "
-            f"({_gb(result['collectives']['moved_bytes_total'])}) "
-            f"compile={result['compile_s']:.0f}s"
-        )
-    return result
-
-
 def _by_op(colls):
     agg = {}
     for c in colls:
@@ -373,36 +230,26 @@ def _gb(x):
     return "n/a" if x is None else f"{x/2**30:.2f}GiB"
 
 
-def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
-    """Dry-run the paper's production engine chunk on the big mesh.
+def lower_engine_chunk(devices, cfg, bucket_size: int, edge_cap: int):
+    """Lower + compile the engine's jitted chunk from abstract arguments.
 
-    Solver sizing: web-scale synthetic instance, N = 16.7M nodes packed in
-    4096-slot buckets, 6 real + 2 headroom buckets per device.
+    ``devices`` are flattened into the engine's one ``pid`` axis (so
+    ``cfg.k == len(devices)``); the chunk is the per-edge
+    (``segment_sum``) path with ``cfg.buckets_per_dev`` rows of
+    ``bucket_size`` slots and ``edge_cap`` edges per device row.  No host
+    array is built.
     """
-    from repro.core.distributed import EngineConfig
-    from repro.launch.mesh import make_production_mesh
-
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    k = int(np.prod(mesh.devices.shape))
-    axis = mesh.axis_names  # treat the whole mesh as one pid axis
-    # flatten mesh to a single 'pid' axis view for the solver
-    flat_mesh = jax.sharding.Mesh(
-        mesh.devices.reshape(-1), ("pid",), **mesh_axis_types_kw(1)
-    )
-    cfg = EngineConfig(
-        k=k, target_error=1e-8, eps=0.15,
-        buckets_per_dev=8, headroom=2, chunk_rounds=1,
-    )
-    bucket_size = 4096
-    edge_cap = bucket_size * 16  # L/N ~ 12.9 with skew headroom
-    r = k * cfg.buckets_per_dev
-    from repro.core.distributed import DistributedEngine
+    from repro.core.distributed import DistributedEngine, EngineState
 
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d)
-    # build the engine chunk directly with abstract args (no host arrays)
+    k = cfg.k
+    flat_mesh = jax.sharding.Mesh(
+        np.asarray(devices).reshape(-1), ("pid",), **mesh_axis_types_kw(1)
+    )
+    assert flat_mesh.devices.size == k, (flat_mesh.devices.size, k)
+    r = k * cfg.buckets_per_dev
     eng = DistributedEngine.__new__(DistributedEngine)
     eng.a = type("A", (), {"bucket_size": bucket_size, "n_rows": r,
                            "edge_cap": edge_cap})()
@@ -411,8 +258,7 @@ def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
     eng.mesh = flat_mesh
     run_chunk = DistributedEngine._build_chunk(eng)
 
-    from repro.core.distributed import EngineState
-
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d)
     dt = jnp.float32
     row = lambda *s: sds(tuple(s), dt)
     rowi = lambda *s: sds(tuple(s), jnp.int32)
@@ -436,13 +282,34 @@ def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
             rowi(r, edge_cap), rowi(r, edge_cap), row(r, edge_cap))
     shards = (state_sh, sh(P("pid")), sh(P("pid")), sh(P("pid")),
               sh(P("pid")), sh(P("pid")))
-    t0 = time.time()
     with flat_mesh:
         lowered = jax.jit(
             lambda s, w, ss, db, dsl, wg: run_chunk(s, w, ss, db, dsl, wg),
             in_shardings=shards,
         ).lower(*args)
-        compiled = lowered.compile()
+        return lowered.compile()
+
+
+def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
+    """Dry-run the paper's production engine chunk on the big mesh.
+
+    Solver sizing: web-scale synthetic instance, N = 16.7M nodes packed in
+    4096-slot buckets, 6 real + 2 headroom buckets per device.
+    """
+    from repro.core.distributed import EngineConfig
+    from repro.launch.mesh import make_production_mesh
+
+    mesh = make_production_mesh(multi_pod=multi_pod)
+    k = int(np.prod(mesh.devices.shape))
+    cfg = EngineConfig(
+        k=k, target_error=1e-8, eps=0.15,
+        buckets_per_dev=8, headroom=2, chunk_rounds=1,
+    )
+    bucket_size = 4096
+    edge_cap = bucket_size * 16  # L/N ~ 12.9 with skew headroom
+    r = k * cfg.buckets_per_dev
+    t0 = time.time()
+    compiled = lower_engine_chunk(mesh.devices, cfg, bucket_size, edge_cap)
     mem = compiled.memory_analysis()
     ca = cost_analysis_dict(compiled)
     colls = parse_collectives(compiled.as_text())
@@ -482,68 +349,23 @@ def lower_solver(multi_pod: bool, out_dir: str = None, verbose=True):
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch")
-    ap.add_argument("--cell")
+    ap = argparse.ArgumentParser(
+        description="Lower + compile the engine chunk on a production mesh "
+        "of placeholder CPU devices.")
+    ap.add_argument("--solver", action="store_true",
+                    help="lower the paper's engine chunk (the one entry)")
     ap.add_argument("--mesh", choices=["single", "multi", "both"],
                     default="both")
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--solver", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    if not args.solver:
+        ap.error("nothing to lower: pass --solver")
+    # the placeholder devices must exist before jax initialises its backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     out = args.out or os.path.abspath(RESULTS_DIR)
-
-    meshes = {"single": [False], "multi": [True],
-              "both": [False, True]}[args.mesh]
-
-    if args.solver:
-        for mp in meshes:
-            lower_solver(mp, out_dir=out)
-        return
-
-    if args.all:
-        import subprocess
-
-        from repro.configs import ARCH_IDS, get_arch
-
-        failures = []
-        for aid in ARCH_IDS:
-            spec = get_arch(aid)
-            for cname in spec.cells:
-                for mp in meshes:
-                    mesh_name = "multi" if mp else "single"
-                    fname = os.path.join(
-                        out,
-                        f"{aid}__{cname}__"
-                        f"{'pod2x16x16' if mp else 'pod16x16'}.json")
-                    if os.path.exists(fname):
-                        print(f"[skip] {aid} × {cname} × {mesh_name} (done)")
-                        continue
-                    cmd = [sys.executable, "-m", "repro.launch.dryrun",
-                           "--arch", aid, "--cell", cname,
-                           "--mesh", mesh_name, "--out", out]
-                    r = subprocess.run(cmd, capture_output=True, text=True,
-                                       timeout=3600)
-                    sys.stdout.write(r.stdout)
-                    if r.returncode != 0:
-                        failures.append((aid, cname, mesh_name))
-                        print(f"[FAIL] {aid} × {cname} × {mesh_name}:\n"
-                              + r.stderr[-2000:])
-        # the solver entries
-        for mp in meshes:
-            try:
-                lower_solver(mp, out_dir=out)
-            except Exception:
-                traceback.print_exc()
-                failures.append(("solver", "chunk", str(mp)))
-        print(f"\n{'=' * 60}\nfailures: {len(failures)}")
-        for f_ in failures:
-            print("  FAIL:", f_)
-        sys.exit(1 if failures else 0)
-
-    assert args.arch and args.cell, "--arch and --cell (or --all)"
-    for mp in meshes:
-        lower_cell(args.arch, args.cell, mp, out_dir=out)
+    for mp in {"single": [False], "multi": [True],
+               "both": [False, True]}[args.mesh]:
+        lower_solver(mp, out_dir=out)
 
 
 if __name__ == "__main__":

@@ -1,17 +1,16 @@
-"""Production mesh definitions (DESIGN.md §6).
+"""Production mesh for the engine's dry run (DESIGN.md §6).
 
 Single pod: (data=16, model=16) = 256 chips (TPU v5e pod slice).
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips; ``pod`` is an outer
-data-parallel axis so the only cross-pod (DCN) traffic is the gradient
-all-reduce — which the gradient-compression path (repro.optim.compression)
-targets.
+Multi-pod:  (pod=2, data=16, model=16) = 512 chips.
+``repro.launch.dryrun.lower_solver`` flattens either mesh into the
+engine's one ``pid`` axis.
 
-Defined as functions so importing this module never touches jax device
+Defined as a function so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before any jax initialisation).
 """
 from __future__ import annotations
 
-__all__ = ["make_production_mesh", "mesh_rules"]
+__all__ = ["make_production_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,9 +19,3 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes, **mesh_axis_types_kw(len(axes)))
-
-
-def mesh_rules(multi_pod: bool):
-    from repro.parallel.axes import DEFAULT_RULES, SINGLE_AXIS_RULES
-
-    return DEFAULT_RULES if multi_pod else SINGLE_AXIS_RULES

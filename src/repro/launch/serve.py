@@ -1,12 +1,4 @@
-"""Serving drivers.
-
-Two modes:
-
-* ``lm`` (default, legacy invocation) — batched prefill + decode for
-  any LM arch (reduced config on CPU; production shardings proven by
-  the decode/prefill dry-run cells).
-
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --gen 16
+"""Serving driver: one mode, ``rank`` (the mode word is optional).
 
 * ``rank`` — warm-start multi-RHS PageRank serving on a
   :class:`repro.SolverSession` (DESIGN.md §4): one cold solve builds
@@ -38,7 +30,7 @@ Two modes:
   --rescale-k K`` shrinks/grows the engine's pid axis mid-stream
   (device loss / scale-up) without recomputing H — engine methods only.
 
-    PYTHONPATH=src python -m repro.launch.serve rank --n 20000 --requests 8
+    PYTHONPATH=src python -m repro.launch.serve --n 20000 --requests 8
     PYTHONPATH=src python -m repro.launch.serve rank --churn 0.01 \\
         --churn-every 3
     PYTHONPATH=src python -m repro.launch.serve rank --ckpt-dir /tmp/ck \\
@@ -49,55 +41,6 @@ import sys
 import time
 
 import numpy as np
-
-
-def lm_main(argv):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import ARCH_IDS, get_arch
-    from repro.configs.smoke import smoke_setup
-    from repro.data import lm_token_batch
-    from repro.models import transformer as lm
-
-    ap = argparse.ArgumentParser(prog="serve [lm]")
-    ap.add_argument("--arch", required=True,
-                    choices=[a for a in ARCH_IDS])
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
-    args = ap.parse_args(argv)
-
-    spec = get_arch(args.arch)
-    assert spec.family == "lm", "serving applies to the LM archs"
-    cfg, _, _ = smoke_setup(args.arch)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    max_seq = args.prompt + args.gen
-    prompts = jnp.asarray(
-        lm_token_batch(0, args.batch, args.prompt, cfg.vocab)["tokens"])
-
-    prefill = jax.jit(lambda p, t: lm.prefill_step(p, t, cfg,
-                                                   max_seq=max_seq))
-    decode = jax.jit(lambda p, c, t: lm.decode_step(p, c, t, cfg))
-
-    t0 = time.time()
-    cache, logits = prefill(params, prompts)
-    jax.block_until_ready(logits)
-    print(f"[{args.arch}] prefill {args.batch}x{args.prompt}: "
-          f"{(time.time()-t0)*1e3:.0f} ms")
-    toks = jnp.argmax(logits, -1)
-    outs = [toks]
-    t0 = time.time()
-    for _ in range(args.gen - 1):
-        logits, cache = decode(params, cache, toks)
-        toks = jnp.argmax(logits, -1)
-        outs.append(toks)
-    jax.block_until_ready(toks)
-    dt = time.time() - t0
-    print(f"decode {args.gen-1} steps: {dt*1e3:.0f} ms "
-          f"({args.batch*(args.gen-1)/dt:.0f} tok/s)")
-    print("generated ids:",
-          np.stack([np.asarray(t) for t in outs], 1)[0][:12].tolist())
 
 
 def rank_main(argv):
@@ -393,10 +336,8 @@ def main():
     enable_compile_cache()
     argv = sys.argv[1:]
     if argv and argv[0] == "rank":
-        return rank_main(argv[1:])
-    if argv and argv[0] == "lm":
         argv = argv[1:]
-    return lm_main(argv)
+    return rank_main(argv)
 
 
 if __name__ == "__main__":

@@ -1,10 +1,1 @@
-from .axes import (  # noqa: F401
-    axis_rules,
-    current_rules,
-    hint,
-    logical_to_spec,
-)
-from .sharding import (  # noqa: F401
-    input_sharding_specs,
-    param_sharding_specs,
-)
+"""JAX spellings the engine's ``shard_map`` path needs (see ``compat``)."""

@@ -18,6 +18,13 @@ Corner graphs:
 * ``overweight_rows``  — weighted columns summing above 1 (spectral
                           radius still < 1): schedules see transient
                           |F|₁ growth
+
+Block-structure corners (``block_corners.py``): the tile and bucket
+shapes the chip path meets — a partial last tile row (``tile_tail``),
+only diagonal tiles (``block_diagonal``), hosts in popularity order
+(``popsort_hosts``), a single seeded page (``one_seed``), a host that
+never sends (``dangling_host``) or never receives (``unreached_block``)
+fluid, a full tile row (``hub_out``) and one hot column (``hub_in``).
 """
 import numpy as np
 import pytest
@@ -25,6 +32,8 @@ import pytest
 import repro
 from repro.core import pagerank_system, power_law_graph
 from repro.core.graph import CSRGraph
+
+from block_corners import BLOCK_CORNERS
 
 ALL_BACKENDS = ("sequential", "frontier:segment_sum", "frontier:pallas",
                 "engine:chunk", "engine:bsr", "simulator")
@@ -103,6 +112,7 @@ CORNERS = {
     "dangling_heavy": _dangling_heavy,
     "parallel_edges": _parallel_edges,
     "overweight_rows": _overweight_rows,
+    **BLOCK_CORNERS,
 }
 
 
@@ -128,11 +138,14 @@ def test_corner_graph_parity(corner, method):
     assert rep.n_ops > 0
 
 
-def test_dangling_ops_accounting_parity():
+@pytest.mark.parametrize("corner",
+                         ["dangling_heavy", *sorted(BLOCK_CORNERS)])
+def test_ops_accounting_parity(corner):
     """§2.3: every backend charges dangling diffusions 1 op, so the
-    normalized costs stay within schedule slack of each other even when
-    60% of the mass flows through dangling nodes."""
-    problem = CORNERS["dangling_heavy"]()
+    normalized costs stay within schedule slack of each other — even when
+    60% of the mass flows through dangling nodes, and on every block
+    corner the chip path meets."""
+    problem = CORNERS[corner]()
     costs = {}
     for method in ("sequential", "frontier:segment_sum", "engine:chunk"):
         rep = repro.solve(problem, method=method)

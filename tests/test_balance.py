@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from repro.balance import (
-    AdvisoryExecutor,
     CostRefreshPolicy,
     HysteresisPolicy,
     LoadSignal,
@@ -126,17 +125,6 @@ def test_moveplan_through_node_executor(small_pagerank):
     moved = ex.apply(MovePlan(src=0, dst=1, units=10_000, kind="node"))
     assert moved > 0
     assert sim.sets[0].size == 1
-
-
-def test_moveplan_through_advisory_executor():
-    ex = AdvisoryExecutor(kind="device")
-    p1 = MovePlan(src=3, dst=0, units=2, kind="device")
-    p2 = MovePlan(src=1, dst=2, units=1, kind="device")
-    assert ex.apply(p1) == 2
-    assert ex.apply(p2) == 1
-    assert ex.log == [p1, p2]
-    assert ex.drain() == [p1, p2]
-    assert ex.log == []
 
 
 # bucket-executor round-trip rides in the multi-device subprocess test
@@ -269,62 +257,3 @@ def test_simulator_slope_ema_decision_equivalence(skewed_pagerank):
     assert res2.cost_iterations == res.cost_iterations
     assert res2.n_steps == res.n_steps
     np.testing.assert_array_equal(res2.h, res.h)
-
-
-# --------------------------------------------------------------------------- #
-# runtime adapters
-# --------------------------------------------------------------------------- #
-def test_straggler_monitor_reseed_and_log():
-    from repro.runtime import StragglerMonitor
-
-    mon = StragglerMonitor(n_hosts=4, z=2)
-    mv = None
-    for _ in range(8):
-        mv = mon.advise(np.array([0.1, 0.1, 0.1, 0.9])) or mv
-    assert mv is not None and mv.src == 3 and mv.kind == "device"
-    assert len(mon.executor.log) >= 1
-    mon.reseed()
-    assert (mon.policy.ctl.slope == 0).all()
-    assert (mon.policy.ctl.cooldown > 0).all()
-
-
-def test_expert_load_monitor_flags_hot_expert():
-    from repro.runtime import ExpertLoadMonitor
-
-    mon = ExpertLoadMonitor(n_experts=4, z=2)
-    plans = []
-    for _ in range(8):
-        plans += mon.observe(np.array([900.0, 10.0, 10.0, 10.0]))
-    assert plans and plans[0].src == 0
-    assert all(p.kind == "expert-shard" for p in plans)
-    # wrong-width observation is ignored, not fatal
-    assert mon.observe(np.array([1.0, 2.0])) == []
-
-
-def test_moe_expert_tap_feeds_sink():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.models.transformer import (
-        MoEConfig, TransformerConfig, init_params, set_expert_load_sink,
-        train_loss)
-
-    cfg = TransformerConfig(
-        name="t", n_layers=1, d_model=16, n_heads=2, n_kv_heads=1,
-        d_ff=32, vocab=32, dtype=jnp.float32, ce_chunk=8,
-        moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=8, n_shared=0,
-                      pad_experts_to=4))
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    batch = {"tokens": jnp.asarray(rng.integers(0, 32, (2, 8)), jnp.int32),
-             "labels": jnp.asarray(rng.integers(0, 32, (2, 8)), jnp.int32)}
-    seen = []
-    set_expert_load_sink(seen.append)
-    try:
-        loss = jax.jit(lambda p, b: train_loss(p, b, cfg))(params, batch)
-        jax.block_until_ready(loss)
-    finally:
-        set_expert_load_sink(None)
-    assert seen, "expert-load tap must fire under jit"
-    assert seen[0].shape == (4,)
-    assert seen[0].sum() == 2 * 8 * 2  # every (token, top-k slot) routed

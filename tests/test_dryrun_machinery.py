@@ -8,66 +8,44 @@ import textwrap
 SCRIPT = textwrap.dedent(
     """
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import sys
     sys.path.insert(0, {src!r})
-    import dataclasses
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.configs import get_arch
-    from repro.configs.common import ArchSpec, ShapeCell, sds, lm_cells
-    from repro.launch.steps import build_cell_step
-    from repro.launch.dryrun import parse_collectives
-    from repro.parallel.axes import axis_rules
+    from repro.core.distributed import EngineConfig
+    from repro.launch.dryrun import lower_engine_chunk, parse_collectives
     from repro.parallel.compat import cost_analysis_dict
 
-    # a tiny LM spec with the same machinery as the real cells
-    from repro.models.transformer import TransformerConfig
-    cfg = TransformerConfig(
-        name="tiny", n_layers=3, d_model=64, n_heads=4, n_kv_heads=2,
-        d_ff=96, vocab=128, dtype=jnp.float32, ce_chunk=16)
-    cell = ShapeCell(
-        name="train_tiny", kind="train",
-        inputs=lambda: {{"tokens": sds((8, 32), jnp.int32),
-                        "labels": sds((8, 32), jnp.int32)}},
-        input_axes={{"tokens": ("batch", None), "labels": ("batch", None)}},
-        overrides={{"n_microbatches": 2}},
-        meta={{"tokens": 256, "batch": 8, "seq": 32}})
-    spec = ArchSpec(arch_id="tiny-lm", family="lm", model_cfg=cfg,
-                    cells={{"train_tiny": cell}})
-
-    from repro.parallel.compat import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"))
-    rules = {{"batch": "data", "embed": "data", "act_embed": None,
-             "act_seq": "model", "heads": "model", "mlp": "model",
-             "vocab": "model", "kv_seq": "model"}}
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    with axis_rules(rules):
-        step, args, in_specs = build_cell_step(
-            spec, cell, rules, dp_shards=2, axis_sizes=sizes)
-        shards = jax.tree.map(
-            lambda s: NamedSharding(mesh, s), in_specs,
-            is_leaf=lambda x: isinstance(x, P))
-        with mesh:
-            compiled = jax.jit(step, in_shardings=shards).lower(
-                *args).compile()
-    ca = cost_analysis_dict(compiled)
-    assert ca.get("flops", 0) > 0
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes > 0
-    colls = parse_collectives(compiled.as_text(), trip_candidates={{3, 2}})
-    assert len(colls) > 0, "expected collectives on a 2x4 mesh"
-    assert any(c["trips"] == 3 for c in colls), (
-        "layer-scan collectives must be trip-attributed: "
-        + str(sorted({{c['trips'] for c in colls}})))
-    print("DRYRUN_MACHINERY_OK", len(colls))
+    # the engine chunk at k = 4 through the path lower_solver takes, at a
+    # small size: 4 rows of 16 slots a device, 3 exchanges a chunk
+    k, b_loc, s, chunk_rounds = 4, 4, 16, 3
+    cfg = EngineConfig(k=k, target_error=1e-6, eps=0.15,
+                       buckets_per_dev=b_loc, headroom=1,
+                       chunk_rounds=chunk_rounds)
+    compiled = lower_engine_chunk(jax.devices()[:k], cfg, bucket_size=s,
+                                  edge_cap=4 * s)
+    assert cost_analysis_dict(compiled).get("flops", 0) > 0
+    assert compiled.memory_analysis().argument_size_in_bytes > 0
+    colls = parse_collectives(compiled.as_text())
+    # the exchange's psum_scatter: a reduce-scatter of one device's
+    # [b_loc * s] share, or the full-outbox all-reduce a backend may
+    # lower it to (the loop's pmax and the residual sums are scalars)
+    share = b_loc * s * 4
+    exchange = [c for c in colls
+                if c["op"] in ("reduce-scatter", "all-reduce")
+                and c["dtype"] == "f32" and c["result_bytes"] >= share]
+    assert exchange, sorted((c["op"], c["dtype"], c["result_bytes"])
+                            for c in colls)
+    assert all(c["group_size"] == k for c in exchange), exchange
+    n = sum(c["trips"] for c in exchange)
+    assert n == chunk_rounds, (n, chunk_rounds, exchange)
+    print("DRYRUN_MACHINERY_OK", len(colls), n)
     """
 )
 
 
-def test_small_mesh_lowering():
+def test_engine_chunk_collectives():
     src = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     r = subprocess.run(

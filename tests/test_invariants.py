@@ -20,6 +20,8 @@ import repro
 from repro.api.session import _invariant_violation
 from repro.core import pagerank_system, power_law_graph
 
+from block_corners import BLOCK_CORNERS
+
 # (method, session kwargs, invariant rtol) — f64 backends get a tight
 # bound, f32 ones a dtype-scaled bound
 F64_RTOL = 1e-10
@@ -53,26 +55,35 @@ def _check(problem, snapshots, rtol, method):
 
 
 # --------------------------------------------------------------------------- #
-# session-driven backends (frontier + engine), grain = trace round / chunk
+# session-driven backends (frontier + engine), grain = trace round / chunk,
+# on problem400 and on every block-structure corner (block_corners.py)
 # --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("graph", ["problem400", *sorted(BLOCK_CORNERS)])
 @pytest.mark.parametrize("method,opts,rtol", [
     ("frontier:segment_sum", {}, F32_RTOL),
     ("frontier:pallas", {"interpret": True, "bs": 64}, F32_RTOL),
     ("engine:chunk", {}, F32_RTOL),
     ("engine:bsr", {}, F32_RTOL),
 ])
-def test_session_backends_conserve_fluid(problem400, method, opts, rtol):
-    options = repro.SolverOptions(trace_every=4, **opts)
-    session = repro.SolverSession(problem400, method=method,
-                                  options=options)
+def test_session_backends_conserve_fluid(request, graph, method, opts,
+                                        rtol):
+    if graph == "problem400":
+        problem, trace_every = request.getfixturevalue("problem400"), 4
+    else:
+        # 16-round frontier grains still leave every corner 29 or more
+        # grains to check (_check asks for 3) at a quarter of the
+        # per-grain retraces frontier:segment_sum pays at 4
+        problem, trace_every = BLOCK_CORNERS[graph](), 16
+    options = repro.SolverOptions(trace_every=trace_every, **opts)
+    session = repro.SolverSession(problem, method=method, options=options)
 
     def snapshots():
         for rep in session.run():
             f, h = session._driver.fluid()
             yield h, f, rep.residual
 
-    _check(problem400, snapshots(), rtol, method)
-    assert session.residual <= problem400.tol
+    _check(problem, snapshots(), rtol, f"{method} on {graph}")
+    assert session.residual <= problem.tol
 
 
 # --------------------------------------------------------------------------- #

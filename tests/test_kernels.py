@@ -12,7 +12,6 @@ except ImportError:  # optional dep: property tests skip, fallbacks run
     HAVE_HYPOTHESIS = False
 
 from repro.core import CSRGraph, pagerank_system, power_law_graph
-from repro.kernels.attention import attention_ref, flash_attention
 from repro.kernels.diffusion import (
     BsrMatrix,
     bsr_gather_spmm_pallas,
@@ -21,17 +20,6 @@ from repro.kernels.diffusion import (
     frontier_round_bsr,
     frontier_round_ref,
     prepare_bsr,
-)
-from repro.kernels.fm import (
-    fm_interaction,
-    fm_interaction_naive,
-    fm_interaction_ref,
-)
-from repro.kernels.segment import (
-    embedding_bag,
-    embedding_bag_ref,
-    segment_sum_ref,
-    segment_sum_sorted,
 )
 
 RNG = np.random.default_rng(0)
@@ -527,142 +515,3 @@ def test_occupancy_threshold_defers_exactly():
         m, jnp.asarray(f), jnp.asarray(w), jnp.float32(t),
         backend="block")
     assert np.array_equal(np.asarray(f0), np.asarray(fh))
-
-
-# --------------------------------------------------------------------------- #
-# segment
-# --------------------------------------------------------------------------- #
-@pytest.mark.parametrize(
-    "e,d,s", [(100, 4, 7), (513, 8, 64), (2048, 32, 500), (4096, 128, 11)]
-)
-def test_segment_sum_shapes(e, d, s):
-    seg = np.sort(RNG.integers(0, s, e)).astype(np.int32)
-    data = RNG.standard_normal((e, d)).astype(np.float32)
-    out = np.asarray(segment_sum_sorted(jnp.asarray(data), jnp.asarray(seg), s))
-    ref = np.asarray(segment_sum_ref(jnp.asarray(data), jnp.asarray(seg), s))
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
-
-
-def _check_segment_sum(e, d, s, seed):
-    rng = np.random.default_rng(seed)
-    seg = np.sort(rng.integers(0, s, e)).astype(np.int32)
-    data = rng.standard_normal((e, d)).astype(np.float32)
-    out = np.asarray(
-        segment_sum_sorted(jnp.asarray(data), jnp.asarray(seg), s, tile=128)
-    )
-    ref = np.asarray(segment_sum_ref(jnp.asarray(data), jnp.asarray(seg), s))
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
-
-
-if HAVE_HYPOTHESIS:
-
-    @settings(max_examples=15, deadline=None)
-    @given(
-        e=st.integers(1, 600),
-        d=st.sampled_from([1, 3, 8]),
-        s=st.integers(1, 64),
-        seed=st.integers(0, 1000),
-    )
-    def test_segment_sum_property(e, d, s, seed):
-        _check_segment_sum(e, d, s, seed)
-
-
-@pytest.mark.parametrize(
-    "e,d,s,seed", [(1, 1, 1, 0), (257, 3, 5, 11), (600, 8, 64, 3)]
-)
-def test_segment_sum_property_cases(e, d, s, seed):
-    """Deterministic fallback for the property test (no hypothesis)."""
-    _check_segment_sum(e, d, s, seed)
-
-
-@pytest.mark.parametrize("mode", ["sum", "mean", "max"])
-def test_embedding_bag_modes(mode):
-    table = RNG.standard_normal((500, 16)).astype(np.float32)
-    ids = RNG.integers(0, 500, (32, 8)).astype(np.int32)
-    o = np.asarray(embedding_bag(jnp.asarray(table), jnp.asarray(ids),
-                                 mode=mode))
-    r = np.asarray(embedding_bag_ref(jnp.asarray(table), jnp.asarray(ids),
-                                     mode=mode))
-    np.testing.assert_allclose(o, r, rtol=1e-5, atol=1e-5)
-
-
-# --------------------------------------------------------------------------- #
-# fm
-# --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("b,f,d", [(7, 5, 4), (300, 39, 10), (256, 26, 32)])
-def test_fm_vs_naive(b, f, d):
-    v = RNG.standard_normal((b, f, d)).astype(np.float32)
-    o = np.asarray(fm_interaction(jnp.asarray(v)))
-    r = np.asarray(fm_interaction_ref(jnp.asarray(v)))
-    n = np.asarray(fm_interaction_naive(jnp.asarray(v)))
-    np.testing.assert_allclose(o, r, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(r, n, rtol=1e-2, atol=1e-2)
-
-
-def _check_fm(b, f, d, seed):
-    rng = np.random.default_rng(seed)
-    v = rng.standard_normal((b, f, d)).astype(np.float32)
-    o = np.asarray(fm_interaction(jnp.asarray(v)))
-    n = np.asarray(fm_interaction_naive(jnp.asarray(v)))
-    np.testing.assert_allclose(o, n, rtol=5e-2, atol=5e-2)
-
-
-if HAVE_HYPOTHESIS:
-
-    @settings(max_examples=15, deadline=None)
-    @given(
-        b=st.integers(1, 300),
-        f=st.integers(2, 40),
-        d=st.integers(1, 32),
-        seed=st.integers(0, 1000),
-    )
-    def test_fm_property(b, f, d, seed):
-        _check_fm(b, f, d, seed)
-
-
-@pytest.mark.parametrize(
-    "b,f,d,seed", [(1, 2, 1, 0), (17, 13, 7, 9), (300, 40, 32, 5)]
-)
-def test_fm_property_cases(b, f, d, seed):
-    """Deterministic fallback for the property test (no hypothesis)."""
-    _check_fm(b, f, d, seed)
-
-
-# --------------------------------------------------------------------------- #
-# attention
-# --------------------------------------------------------------------------- #
-@pytest.mark.parametrize(
-    "b,hq,hkv,s,dh,causal",
-    [
-        (2, 4, 2, 256, 64, True),
-        (1, 8, 1, 128, 32, True),  # MQA
-        (2, 4, 4, 384, 64, False),  # MHA bidirectional
-        (1, 2, 1, 100, 64, True),  # padded seq
-        (1, 16, 2, 128, 128, True),
-    ],
-)
-def test_flash_attention(b, hq, hkv, s, dh, causal):
-    q = (RNG.standard_normal((b, hq, s, dh)) * 0.2).astype(np.float32)
-    k = (RNG.standard_normal((b, hkv, s, dh)) * 0.2).astype(np.float32)
-    v = RNG.standard_normal((b, hkv, s, dh)).astype(np.float32)
-    o = np.asarray(
-        flash_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                        causal=causal)
-    )
-    r = np.asarray(
-        attention_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                      causal=causal)
-    )
-    np.testing.assert_allclose(o, r, rtol=2e-3, atol=2e-3)
-
-
-def test_flash_attention_bf16():
-    q = (RNG.standard_normal((1, 4, 128, 64)) * 0.2).astype(jnp.bfloat16)
-    k = (RNG.standard_normal((1, 2, 128, 64)) * 0.2).astype(jnp.bfloat16)
-    v = RNG.standard_normal((1, 2, 128, 64)).astype(jnp.bfloat16)
-    o = flash_attention(q, k, v, causal=True)
-    r = attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(
-        np.asarray(o, np.float32), np.asarray(r, np.float32),
-        rtol=3e-2, atol=3e-2,
-    )
